@@ -16,6 +16,8 @@ import sys
 import time
 import traceback
 
+from repro.utils.compile_cache import enable_compile_cache
+
 
 SUITES = [
     ("fig5_strategy_space", "benchmarks.strategy_space"),
@@ -59,6 +61,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if args.all and args.only:
         ap.error("--all and --only are mutually exclusive")
+    enable_compile_cache()
     only = [s for s in args.only.split(",") if s]
     skip = [s for s in args.skip.split(",") if s]
 

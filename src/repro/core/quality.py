@@ -140,8 +140,15 @@ def _slot_copy(dst, src, slot, src_idx):
     }
 
 
+def _put_like(buf, arr):
+    """Host array ``arr`` on the device that holds ``buf``, in its dtype
+    (cast on the host, so only ``buf.dtype`` bytes cross)."""
+    return jax.device_put(np.asarray(arr, buf.dtype), buf.sharding)
+
+
 def inject_kv(cfg, caches, batch_idx: int, kv: KVCache):
-    """Write a (possibly lossy) KVCache back into the cache pytree."""
+    """Write a (possibly lossy) KVCache back into the cache pytree, on the
+    device that holds it."""
     from repro.models.transformer import plan_stack
 
     plan = plan_stack(cfg)
@@ -151,7 +158,7 @@ def inject_kv(cfg, caches, batch_idx: int, kv: KVCache):
     def _store(buf, arr):
         # arr (H, S, D) -> (S, H, D)
         return buf.at[batch_idx, :upto].set(
-            jnp.asarray(arr.transpose(1, 0, 2), buf.dtype))
+            _put_like(buf, arr.transpose(1, 0, 2)))
 
     new_prefix = {}
     for i, spec in enumerate(plan.prefix_specs):
@@ -174,8 +181,8 @@ def inject_kv(cfg, caches, batch_idx: int, kv: KVCache):
         idxs = [li + n * attn_per_period for n in range(plan.n_blocks)]
         karr = np.stack([kv.k[i2].transpose(1, 0, 2) for i2 in idxs])
         varr = np.stack([kv.v[i2].transpose(1, 0, 2) for i2 in idxs])
-        k_buf = c["k"].at[:, batch_idx, :upto].set(jnp.asarray(karr, c["k"].dtype))
-        v_buf = c["v"].at[:, batch_idx, :upto].set(jnp.asarray(varr, c["v"].dtype))
+        k_buf = c["k"].at[:, batch_idx, :upto].set(_put_like(c["k"], karr))
+        v_buf = c["v"].at[:, batch_idx, :upto].set(_put_like(c["v"], varr))
         new_blocks[name] = {"k": k_buf, "v": v_buf}
         li += 1
     return {"prefix": new_prefix, "blocks": new_blocks}
@@ -427,16 +434,17 @@ def _paged_scatter(cfg, pool, bt_row, k_arr, v_arr, upto: int,
     the page-map core of ``inject_kv_paged``/``inject_quant_pages``.
     Only the first ``ceil(upto / page_size)`` owned pages are written
     (partial-page tails are zero-filled; the slot is fresh, so nothing
-    real is clobbered)."""
+    real is clobbered).  The arrays move to the pool's device first."""
     from repro.models.transformer import plan_stack
 
     plan = plan_stack(cfg)
     n_used = -(-upto // page_size)
     rows = jnp.asarray(np.asarray(bt_row)[:n_used], jnp.int32)
+    where = jax.tree_util.tree_leaves(pool)[0].sharding
     li = 0
 
     def _pages(arr):  # (H, S, X) -> (n_used, ps, H, X)
-        a = jnp.asarray(arr).swapaxes(0, 1)  # (S, H, X)
+        a = jax.device_put(arr, where).swapaxes(0, 1)  # (S, H, X)
         a = _pad_axis(a, n_used * page_size, axis=0)
         return a.reshape(n_used, page_size, *a.shape[1:])
 

@@ -113,7 +113,6 @@ def make_cross_pod_grad_sync(mesh: Mesh, grads_example, param_specs,
 
         return jax.tree_util.tree_map(sync_leaf, grads)
 
-    from repro.utils.compat import shard_map_compat
-    mapped = shard_map_compat(body, mesh=mesh, in_specs=(specs,),
-                              out_specs=specs, check=False)
+    mapped = jax.shard_map(body, mesh=mesh, in_specs=(specs,),
+                           out_specs=specs, check_vma=False)
     return jax.jit(mapped)

@@ -102,9 +102,8 @@ def make_kv_transfer(mesh: Mesh, cache_example, bits: int = 4,
     # check_vma=False: with batch=1 cells (long_500k) the pod axis doesn't
     # appear in the value specs, and replication can't be statically
     # inferred through ppermute.
-    from repro.utils.compat import shard_map_compat
-    mapped = shard_map_compat(body, mesh=mesh, in_specs=(specs,),
-                              out_specs=specs, check=False)
+    mapped = jax.shard_map(body, mesh=mesh, in_specs=(specs,),
+                           out_specs=specs, check_vma=False)
     return jax.jit(mapped), specs
 
 

@@ -22,9 +22,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.quant_pack import F32_DOT, dequant_tile
+
 
 def _attn_kernel(q_ref, kc_ref, ks_ref, vc_ref, vs_ref, *rest,
-                 bits: int, group: int, kv_len: Optional[int],
+                 bits: int, kv_len: Optional[int],
                  block_s: int, sm_scale: float):
     if kv_len is None:
         # Multi-slot decode: per-row valid lengths streamed in via SMEM —
@@ -42,25 +44,13 @@ def _attn_kernel(q_ref, kc_ref, ks_ref, vc_ref, vs_ref, *rest,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def _dequant(c_ref, s_ref):
-        c = c_ref[0, 0]  # (BS, D') packed
-        if bits == 4:
-            lo = (c & jnp.uint8(0x0F)).astype(jnp.int32) - 8
-            hi = (c >> jnp.uint8(4)).astype(jnp.int32) - 8
-            q = jnp.stack([lo, hi], axis=-1).reshape(c.shape[0], c.shape[1] * 2)
-        else:
-            q = c.astype(jnp.int32)
-        bs, d = q.shape
-        sc = s_ref[0, 0].astype(jnp.float32)  # (BS, D/group)
-        x = q.reshape(bs, d // group, group).astype(jnp.float32) * sc[..., None]
-        return x.reshape(bs, d)
-
-    k = _dequant(kc_ref, ks_ref)  # (BS, D) f32
-    v = _dequant(vc_ref, vs_ref)
+    k = dequant_tile(kc_ref[0, 0], ks_ref[0, 0], bits)  # (BS, D) f32
+    v = dequant_tile(vc_ref[0, 0], vs_ref[0, 0], bits)
     q = q_ref[0, 0].astype(jnp.float32)  # (Gq, D)
 
     scores = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
+        precision=F32_DOT,
         preferred_element_type=jnp.float32) * sm_scale  # (Gq, BS)
 
     # mask out cache slots beyond kv_len
@@ -74,7 +64,8 @@ def _attn_kernel(q_ref, kc_ref, ks_ref, vc_ref, vs_ref, *rest,
     p = jnp.exp(scores - m_new)   # (Gq, BS)
     l_new = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
     acc = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        p, v, (((1,), (0,)), ((), ())), precision=F32_DOT,
+        preferred_element_type=jnp.float32)
 
     m_scr[...] = m_new
     l_scr[...] = l_new
@@ -112,13 +103,14 @@ def decode_attention(
     assert s % bs == 0, (s, bs)
     cw = k_codes.shape[3]
     ng = k_scale.shape[3]
+    assert ng * group == d, (ng, group, d)
     sm_scale = 1.0 / math.sqrt(d)
 
     multi_slot = kv_len is not None and jnp.ndim(kv_len) == 1
     static_len = s if kv_len is None else (None if multi_slot else int(kv_len))
 
     kernel = functools.partial(
-        _attn_kernel, bits=bits, group=group, kv_len=static_len, block_s=bs,
+        _attn_kernel, bits=bits, kv_len=static_len, block_s=bs,
         sm_scale=sm_scale)
 
     in_specs = [

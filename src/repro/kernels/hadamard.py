@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.quant_pack import F32_DOT
 from repro.kernels.ref import hadamard_matrix
 
 
@@ -23,7 +24,8 @@ def _hadamard_kernel(x_ref, h_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)   # (BT, D)
     h = h_ref[...].astype(jnp.float32)   # (D, D)
     o_ref[...] = jnp.dot(
-        x, h, preferred_element_type=jnp.float32).astype(o_ref.dtype)
+        x, h, precision=F32_DOT,
+        preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
 def hadamard_transform(x: jnp.ndarray, block_tokens: int = 256,

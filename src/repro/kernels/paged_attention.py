@@ -33,10 +33,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.quant_pack import F32_DOT, dequant_tile
+
 
 def _paged_attn_kernel(bt_ref, kvl_ref, q_ref, kc_ref, ks_ref, vc_ref,
                        vs_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                       bits: int, group: int, page_size: int,
+                       bits: int, page_size: int,
                        sm_scale: float):
     del bt_ref  # consumed by the BlockSpec index maps, not the body
     b_idx = pl.program_id(0)
@@ -50,26 +52,13 @@ def _paged_attn_kernel(bt_ref, kvl_ref, q_ref, kc_ref, ks_ref, vc_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def _dequant(c_ref, s_ref):
-        c = c_ref[0, 0]  # (PS, D') packed page
-        if bits == 4:
-            lo = (c & jnp.uint8(0x0F)).astype(jnp.int32) - 8
-            hi = (c >> jnp.uint8(4)).astype(jnp.int32) - 8
-            q = jnp.stack([lo, hi], axis=-1).reshape(c.shape[0],
-                                                     c.shape[1] * 2)
-        else:
-            q = c.astype(jnp.int32)
-        ps, d = q.shape
-        sc = s_ref[0, 0].astype(jnp.float32)  # (PS, D/group)
-        x = q.reshape(ps, d // group, group).astype(jnp.float32) * sc[..., None]
-        return x.reshape(ps, d)
-
-    k = _dequant(kc_ref, ks_ref)  # (PS, D) f32
-    v = _dequant(vc_ref, vs_ref)
+    k = dequant_tile(kc_ref[0, 0], ks_ref[0, 0], bits)  # (PS, D) f32
+    v = dequant_tile(vc_ref[0, 0], vs_ref[0, 0], bits)
     q = q_ref[0, 0].astype(jnp.float32)  # (Gq, D)
 
     scores = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
+        precision=F32_DOT,
         preferred_element_type=jnp.float32) * sm_scale  # (Gq, PS)
 
     # Mask positions at/beyond this slot's length (covers scratch pages).
@@ -83,7 +72,8 @@ def _paged_attn_kernel(bt_ref, kvl_ref, q_ref, kc_ref, ks_ref, vc_ref,
     p = jnp.exp(scores - m_new)   # (Gq, PS)
     l_new = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
     acc = acc_scr[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        p, v, (((1,), (0,)), ((), ())), precision=F32_DOT,
+        preferred_element_type=jnp.float32)
 
     m_scr[...] = m_new
     l_scr[...] = l_new
@@ -119,10 +109,11 @@ def paged_attention(
     assert hkv_k == hkv, (hkv_k, hkv)
     assert cw == (d if bits == 8 else d // 2), (cw, d, bits)
     ng = k_scale.shape[3]
+    assert ng * group == d, (ng, group, d)
     pps = block_tables.shape[1]
     sm_scale = 1.0 / math.sqrt(d)
 
-    kernel = functools.partial(_paged_attn_kernel, bits=bits, group=group,
+    kernel = functools.partial(_paged_attn_kernel, bits=bits,
                                page_size=ps, sm_scale=sm_scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

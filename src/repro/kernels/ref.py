@@ -37,16 +37,20 @@ def dequantize_ref(codes: jnp.ndarray, scale: jnp.ndarray, group: int,
 
 
 def pack_int4_ref(codes: jnp.ndarray) -> jnp.ndarray:
-    """int8 codes in [-8,7] -> packed uint8 (last dim halved)."""
+    """int8 codes in [-8,7] -> packed uint8 (last dim halved).
+
+    Half-split layout: the low nibbles hold channels ``[0, D/2)`` and the
+    high nibbles ``[D/2, D)``.  Both halves are contiguous lane slices, which
+    Mosaic lowers; a lane-strided even/odd interleave it does not."""
     u = (codes.astype(jnp.int32) + 8).astype(jnp.uint8)
-    return (u[..., 0::2] | (u[..., 1::2] << 4)).astype(jnp.uint8)
+    h = u.shape[-1] // 2
+    return (u[..., :h] | (u[..., h:] << 4)).astype(jnp.uint8)
 
 
 def unpack_int4_ref(packed: jnp.ndarray) -> jnp.ndarray:
     lo = (packed & jnp.uint8(0x0F)).astype(jnp.int32) - 8
     hi = (packed >> jnp.uint8(4)).astype(jnp.int32) - 8
-    out = jnp.stack([lo, hi], axis=-1)
-    return out.reshape(packed.shape[:-1] + (packed.shape[-1] * 2,)).astype(jnp.int8)
+    return jnp.concatenate([lo, hi], axis=-1).astype(jnp.int8)
 
 
 def quant_pack_ref(x: jnp.ndarray, bits: int, group: int

@@ -40,12 +40,8 @@ _EW_FLOP_OPS = {
 
 
 def xla_cost_analysis(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` normalized across jaxlib versions:
-    older jaxlibs return ``[dict]``, newer ones return the dict directly."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, list):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """``compiled.cost_analysis()``, empty when XLA reports nothing."""
+    return compiled.cost_analysis() or {}
 
 
 def _shape_list(text: str) -> List[Tuple[str, Tuple[int, ...]]]:

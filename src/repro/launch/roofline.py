@@ -160,8 +160,6 @@ def analyze(
         coll["total"] = walked.coll_bytes
     except Exception:
         cost = compiled.cost_analysis()
-        if isinstance(cost, list):  # older API returns [dict]
-            cost = cost[0] if cost else {}
         flops = _cost_get(cost, "flops")
         bytes_accessed = _cost_get(cost, "bytes accessed")
         coll = collective_bytes(lowered_text)

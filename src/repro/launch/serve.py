@@ -16,6 +16,7 @@ from repro.core.profiles import load_profiles
 from repro.data.synthetic import WORKLOADS
 from repro.serving.engine import DisaggregatedEngine
 from repro.serving.network import GBPS, BandwidthTrace
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main(argv=None) -> int:
@@ -28,6 +29,7 @@ def main(argv=None) -> int:
     ap.add_argument("--q-min", type=float, default=0.9)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.profiles:
         profiles = load_profiles(args.profiles)

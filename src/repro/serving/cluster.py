@@ -42,7 +42,7 @@ the pinned PR-1 token fixture holds bit-for-bit in both ``pool`` and
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional, Tuple, Union
+from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -139,6 +139,16 @@ class LoadAwareRouter(Router):
 ROUTERS = {"round_robin": RoundRobinRouter, "load_aware": LoadAwareRouter}
 
 
+def _per_worker(devices: Optional[Sequence[Any]], n: int,
+                kind: str) -> List[Any]:
+    if devices is None:
+        return [None] * n
+    if len(devices) != n:
+        raise ValueError(f"{len(devices)} {kind} devices for {n} "
+                         f"{kind} workers")
+    return list(devices)
+
+
 # ---------------------------------------------------------------------------
 # The cluster runtime
 # ---------------------------------------------------------------------------
@@ -156,7 +166,12 @@ class ClusterRuntime:
                  n_prefill: Optional[int] = None,
                  n_decode: Optional[int] = None,
                  router: Union[str, Router] = "load_aware",
-                 slots_per_worker: Optional[int] = None):
+                 slots_per_worker: Optional[int] = None,
+                 prefill_devices: Optional[Sequence[Any]] = None,
+                 decode_devices: Optional[Sequence[Any]] = None):
+        """``prefill_devices`` / ``decode_devices`` place worker ``i`` on
+        the ``i``-th ``jax.Device`` (one worker per chip); None keeps every
+        worker on JAX's default device."""
         self.cfg = config or RuntimeConfig()
         self.controller = controller
         self.static_profile = static_profile
@@ -193,13 +208,15 @@ class ClusterRuntime:
         # ---- workers ----
         n_slots = (slots_per_worker if slots_per_worker is not None
                    else self.scheduler.cfg.max_slots)
+        pdev = _per_worker(prefill_devices, self.n_prefill, "prefill")
+        ddev = _per_worker(decode_devices, self.n_decode, "decode")
         self.prefill_workers = [
             PrefillWorker(i, self._model, self.cfg, controller,
-                          static_profile)
+                          static_profile, device=pdev[i])
             for i in range(self.n_prefill)]
         self.decode_workers = [
             DecodeWorker(j, self._model, self.cfg, n_slots,
-                         self._build_store(store, j))
+                         self._build_store(store, j), device=ddev[j])
             for j in range(self.n_decode)]
         if self.n_decode == 1 and n_slots == self.scheduler.cfg.max_slots:
             # Legacy introspection parity: with a single decode worker the

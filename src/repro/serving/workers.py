@@ -329,6 +329,34 @@ class ModelHandle:
     params: Any
 
 
+class _Placed:
+    """Device placement shared by both worker kinds.  ``device`` None (the
+    default) leaves every array on JAX's default device; a given device
+    holds the worker's params, arena and page pools, and arrays made on
+    another device reach it only through an explicit ``jax.device_put``."""
+
+    device: Optional[jax.Device] = None
+    model: ModelHandle
+    _params_src: Any = None
+    _params_dev: Any = None
+
+    @property
+    def params(self):
+        """The model's params on this worker's device, copied there once
+        per runtime-level params swap."""
+        src = self.model.params
+        if self.device is None:
+            return src
+        if self._params_src is not src:
+            self._params_dev = jax.device_put(src, self.device)
+            self._params_src = src
+        return self._params_dev
+
+    def _put(self, tree):
+        return tree if self.device is None else jax.device_put(tree,
+                                                               self.device)
+
+
 def codec_cost(cfg: RuntimeConfig, measured: float, nbytes: float,
                speed: float) -> float:
     """Codec stage cost: measured wall-clock, or — under the virtual
@@ -341,7 +369,7 @@ def codec_cost(cfg: RuntimeConfig, measured: float, nbytes: float,
 # ---------------------------------------------------------------------------
 # Prefill worker
 # ---------------------------------------------------------------------------
-class PrefillWorker:
+class PrefillWorker(_Placed):
     """One prefill engine of the cluster: runs real batch-1 prefills,
     selects/compresses the KV it ships, and carries the codec-cost model.
     Requests placed on the same worker within an iteration serialize on it
@@ -349,10 +377,12 @@ class PrefillWorker:
 
     def __init__(self, wid: int, model: ModelHandle, cfg: RuntimeConfig,
                  controller: Optional[ServiceAwareController] = None,
-                 static_profile: Optional[Profile] = None):
+                 static_profile: Optional[Profile] = None,
+                 device: Optional[jax.Device] = None):
         self.wid = wid
         self.name = f"p{wid}"
         self.model = model
+        self.device = device
         self.cfg = cfg
         self.controller = controller
         self.static_profile = static_profile
@@ -383,7 +413,7 @@ class PrefillWorker:
         t_prefill)`` with ``t_prefill`` under the configured cost model."""
         pre1 = self._prefill_fn()
         t0 = time.perf_counter()
-        logits, caches = pre1(self.model.params, {"tokens": tokens[None, :]})
+        logits, caches = pre1(self.params, {"tokens": tokens[None, :]})
         # lint: sync-ok(measures real prefill wall-clock for the EWMA model)
         jax.block_until_ready(logits)
         t_wall = time.perf_counter() - t0
@@ -430,16 +460,18 @@ class PrefillWorker:
 # ---------------------------------------------------------------------------
 # Decode worker
 # ---------------------------------------------------------------------------
-class DecodeWorker:
+class DecodeWorker(_Placed):
     """One decode engine of the cluster: a fixed-capacity slot arena (ONE
     cache pytree, leading axis ``n_slots``), a LIFO local slot-id pool,
     and the worker's decode-side KV tier hierarchy."""
 
     def __init__(self, wid: int, model: ModelHandle, cfg: RuntimeConfig,
-                 n_slots: int, store: Any):
+                 n_slots: int, store: Any,
+                 device: Optional[jax.Device] = None):
         self.wid = wid
         self.name = f"d{wid}"
         self.model = model
+        self.device = device
         self.cfg = cfg
         self.n_slots = n_slots
         self.store = store
@@ -494,18 +526,24 @@ class DecodeWorker:
                 raise NotImplementedError(
                     "slot arena masking assumes attention-only caches "
                     "(SSM states advance unmasked)")
-            if self.cfg.paged:
-                num_pages = (self.cfg.arena_pages
-                             or self.n_slots * self._pps + 1)
-                self.page_table = PageTable(num_pages, self.cfg.page_size)
-                # Per-channel scale layout in the sim pools (group=1):
-                # any strategy group maps onto it by broadcasting its
-                # group scale, so one pool serves every eligible profile.
-                self._arena, self._qcodes, self._qscales = init_paged_pools(
-                    self.model.cfg, num_pages, self.cfg.page_size, group=1)
-            else:
-                self._arena = init_cache(self.model.cfg, self.n_slots,
-                                         self.max_len)
+            # Allocated on the worker's device directly: never staged
+            # through the default device, which may be another worker's.
+            with jax.default_device(self.device):
+                if self.cfg.paged:
+                    num_pages = (self.cfg.arena_pages
+                                 or self.n_slots * self._pps + 1)
+                    self.page_table = PageTable(num_pages,
+                                                self.cfg.page_size)
+                    # Per-channel scale layout in the sim pools (group=1):
+                    # any strategy group maps onto it by broadcasting its
+                    # group scale, so one pool serves every eligible
+                    # profile.
+                    self._arena, self._qcodes, self._qscales = (
+                        init_paged_pools(self.model.cfg, num_pages,
+                                         self.cfg.page_size, group=1))
+                else:
+                    self._arena = init_cache(self.model.cfg, self.n_slots,
+                                             self.max_len)
         return self._arena
 
     def _arena_fn(self):
@@ -528,8 +566,10 @@ class DecodeWorker:
 
     def copy_from_caches(self, caches, idx: int) -> None:
         """Materialize arena row ``idx`` from a prefill worker's batch-1
-        cache (the cold path's slot hand-off)."""
+        cache (the cold path's slot hand-off; the cache moves from the
+        prefill worker's device to this one by ``device_put``)."""
         self.ensure_arena()
+        caches = self._put(caches)
         if self.cfg.paged:
             self.page_table.ensure(idx, self.cfg.seq)
             row = self.page_table.block_row(idx, self._pps)
@@ -666,7 +706,7 @@ class DecodeWorker:
                                        int(self._positions[slot.idx]) + 1)
             t0 = time.perf_counter()
             nxt, self._arena = dec(
-                self.model.params, self._arena, self._qcodes,
+                self.params, self._arena, self._qcodes,
                 self._qscales, jnp.asarray(self._block_tables()),
                 jnp.asarray(self._quant_len),
                 jnp.asarray(self._last_tok[:, None]),
@@ -674,7 +714,7 @@ class DecodeWorker:
         else:
             t0 = time.perf_counter()
             nxt, self._arena = dec(
-                self.model.params, self._arena,
+                self.params, self._arena,
                 jnp.asarray(self._last_tok[:, None]),
                 jnp.asarray(self._positions), jnp.asarray(mask))
         # lint: sync-ok(the step's single sanctioned sync - one batched pull)
@@ -717,14 +757,14 @@ class DecodeWorker:
                 self.page_table.ensure(slot.idx, need)
             t0 = time.perf_counter()
             out, self._arena = fn(
-                self.model.params, self._arena, self._qcodes,
+                self.params, self._arena, self._qcodes,
                 self._qscales, jnp.asarray(self._block_tables()),
                 jnp.asarray(self._quant_len), jnp.asarray(toks),
                 jnp.asarray(self._positions), jnp.asarray(mask))
         else:
             t0 = time.perf_counter()
             out, self._arena = fn(
-                self.model.params, self._arena, jnp.asarray(toks),
+                self.params, self._arena, jnp.asarray(toks),
                 jnp.asarray(self._positions), jnp.asarray(mask))
         # lint: sync-ok(the step's single sanctioned sync - one batched pull)
         out = np.asarray(out)

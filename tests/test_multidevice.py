@@ -128,3 +128,61 @@ def test_dryrun_tiny_both_meshes():
         cwd=str(ROOT))
     assert r.returncode == 0, r.stdout + r.stderr[-2000:]
     assert r.stdout.count("[ok]") == 4
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_pd_cluster_one_worker_per_device(paged):
+    """A 2x2 PD ClusterRuntime with one worker per (forced) device: each
+    worker's params, arena and page pools stay on its own device, the
+    hand-off crosses devices, and greedy tokens match a 1x1 run on the
+    default device.  The paged variant injects the wire-restored KV, so
+    the injection path writes across devices too."""
+    out = _run(f"""
+import jax, numpy as np
+import repro.serving.cluster as C
+from repro.configs import get_config
+from repro.core.profiles import Profile
+from repro.core.strategy import StrategyConfig
+from repro.models import init_params
+from repro.serving.scheduler import SchedulerConfig
+from repro.serving.workers import RuntimeConfig
+
+cfg = get_config("tiny-lm")
+params, _ = init_params(cfg, seed=3)
+C.get_reference_model = lambda: (cfg, params)   # seeded weights, no training
+profile = Profile(StrategyConfig(quantizer="uniform", key_bits=8,
+                                 value_bits=8, granularity="per_channel"),
+                  cr=2.0, s_enc=5e8, s_dec=5e8)
+devs = jax.devices()
+assert len(devs) == 4
+
+def serve(n, pdev, ddev):
+    rt = C.ClusterRuntime(
+        static_profile=profile, router="round_robin", n_prefill=n,
+        n_decode=n, prefill_devices=pdev, decode_devices=ddev,
+        config=RuntimeConfig(seq=32, decode_tokens=6, mode="pd",
+                             paged={paged}, page_size=8,
+                             pd_inject_restored={paged}),
+        scheduler=SchedulerConfig(max_slots=4, max_prefills_per_step=2))
+    for i in range(8):
+        rt.submit(("qalike", "codelike", "mathlike", "summlike")[i % 4],
+                  prompt_seed=100 + i)
+    rt.run()
+    assert len(rt.completed) == 8
+    return rt, {{r.rid: r.tokens.tolist() for r in rt.completed}}
+
+_, one = serve(1, None, None)
+rt, two = serve(2, devs[:2], devs[2:])
+assert two == one, (one, two)
+assert {{r.route for r in rt.completed}} == {{"p0->d0", "p0->d1", "p1->d0",
+                                              "p1->d1"}}
+on = lambda tree, d: all(x.devices() == {{d}}
+                         for x in jax.tree_util.tree_leaves(tree))
+for w, d in zip(rt.prefill_workers, devs[:2]):
+    assert on(w.params, d), w.name
+for w, d in zip(rt.decode_workers, devs[2:]):
+    assert on((w.params, w._arena, w._qcodes, w._qscales), d), w.name
+    assert (w._qcodes is not None) == {paged}
+print("ok")
+""", devices=4)
+    assert "ok" in out
