@@ -85,28 +85,37 @@ def get_reference_model(steps: int = REF_STEPS, seed: int = 0):
 # Cache <-> KVCache conversion (attention layers, dense stacks)
 # ---------------------------------------------------------------------------
 def extract_kv(cfg, caches, batch_idx: int, upto: int) -> KVCache:
-    """Pull one batch element's attention KV as (L, H, S, D) numpy."""
+    """Pull one batch element's attention KV as (L, H, S, D) numpy: one
+    device->host pull per layer and tensor (span ``kv_pull``)."""
     from repro.models.transformer import plan_stack
+    from repro.serving import tracing
 
     plan = plan_stack(cfg)
     ks: List[np.ndarray] = []
     vs: List[np.ndarray] = []
-    for i, spec in enumerate(plan.prefix_specs):
-        if spec.kind != "attn":
-            continue
-        c = caches["prefix"][f"layer{i}"]
-        ks.append(np.asarray(c["k"][batch_idx, :upto], np.float32).transpose(1, 0, 2))
-        vs.append(np.asarray(c["v"][batch_idx, :upto], np.float32).transpose(1, 0, 2))
-    for blk in range(plan.n_blocks):
-        for j, spec in enumerate(plan.period_specs):
+    with tracing.span("kv_pull") as sp:
+        for i, spec in enumerate(plan.prefix_specs):
             if spec.kind != "attn":
                 continue
-            c = caches["blocks"][f"layer{j}"]
-            ks.append(np.asarray(c["k"][blk, batch_idx, :upto],
+            c = caches["prefix"][f"layer{i}"]
+            ks.append(np.asarray(c["k"][batch_idx, :upto],
                                  np.float32).transpose(1, 0, 2))
-            vs.append(np.asarray(c["v"][blk, batch_idx, :upto],
+            vs.append(np.asarray(c["v"][batch_idx, :upto],
                                  np.float32).transpose(1, 0, 2))
-    return KVCache(np.stack(ks), np.stack(vs))
+        for blk in range(plan.n_blocks):
+            for j, spec in enumerate(plan.period_specs):
+                if spec.kind != "attn":
+                    continue
+                c = caches["blocks"][f"layer{j}"]
+                ks.append(np.asarray(c["k"][blk, batch_idx, :upto],
+                                     np.float32).transpose(1, 0, 2))
+                vs.append(np.asarray(c["v"][blk, batch_idx, :upto],
+                                     np.float32).transpose(1, 0, 2))
+        kv = KVCache(np.stack(ks), np.stack(vs))
+        if sp:
+            sp.add(bytes=kv.k.nbytes + kv.v.nbytes,
+                   transfers=len(ks) + len(vs))
+    return kv
 
 
 def copy_cache_slot(cfg, dst, src, slot, src_idx: int = 0):

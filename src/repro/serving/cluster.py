@@ -69,6 +69,7 @@ from repro.serving.network import (
     seed_bandwidth,
 )
 from repro.serving.request import Request, kv_bytes_for
+from repro.serving import tracing
 from repro.serving.scheduler import ContinuousScheduler, SchedulerConfig
 from repro.serving.topology import NetworkTopology, route_name
 from repro.serving.workers import (
@@ -487,7 +488,6 @@ class ClusterRuntime:
         pays encode time to cross a slow link with fewer bytes.  Returns
         the source-side re-encode time spent ON the request's critical
         path (0.0 when the stored route wins)."""
-        import time as _time
         select_fetch = getattr(self.controller, "select_fetch", None)
         if select_fetch is None:
             return 0.0
@@ -528,14 +528,14 @@ class ClusterRuntime:
         decision = select_fetch(ctx, [stored, reenc])
         if decision is None or decision.option.variant != "reencoded":
             return 0.0
-        t0 = _time.perf_counter()
-        if not dw.store.reencode(hit, small):
+        with tracing.timed("reencode") as sp:
+            reencoded = dw.store.reencode(hit, small)
+        if not reencoded:
             return 0.0
         # The re-encode happens before the bytes can cross the link: its
         # cost (the enc term of the fetch decision) is on the critical
         # path — measured wall-clock, or V/s_enc under the virtual clock.
-        return codec_cost(self.cfg, _time.perf_counter() - t0, e.kv_bytes,
-                          small.s_enc)
+        return codec_cost(self.cfg, sp.seconds, e.kv_bytes, small.s_enc)
 
     def _start_request(self, req: Request, route: Route, now: float,
                        busy: float) -> Tuple[float, float]:
@@ -552,10 +552,7 @@ class ClusterRuntime:
         key = req.prefix_key
         idx = req.slot
         dw.ensure_arena()
-        # full=True: a partial (block-aligned) prefix hit would leave the
-        # uncovered prompt suffix without KV — the runtime has no top-up
-        # prefill, so only a full-coverage entry counts as a pool hit.
-        hit = dw.store.lookup(key, now=now, full=True)
+        hit = self._lookup(dw, key, now)
         bd: Dict[str, float] = {"queue": now - req.arrival}
 
         if hit is not None:
@@ -598,10 +595,14 @@ class ClusterRuntime:
         # critical path (it still contends with fetches there); its cost
         # is booked to pool_write, and the controller observes the
         # request's critical-path latency at _finish instead.
-        wr = dw.store.write(
-            key, (comp, first, profile.s_dec), wire, kv_bytes=ctx.kv_bytes,
-            workload=req.workload, slo_class=req.slo_class,
-            ready=now + busy + t_prefill + t_compress, tier=0)
+        with tracing.span("store_put") as sp:
+            wr = dw.store.write(
+                key, (comp, first, profile.s_dec), wire,
+                kv_bytes=ctx.kv_bytes, workload=req.workload,
+                slo_class=req.slo_class,
+                ready=now + busy + t_prefill + t_compress, tier=0)
+            if sp:
+                sp.add(bytes=wire)
         req.state = "decoding"
         end = busy + t_prefill
         slot = Slot(req=req, idx=idx, toks=[first], pool_hit=False,
@@ -630,7 +631,7 @@ class ClusterRuntime:
         idx = req.slot
         bd: Dict[str, float] = {"queue": now - req.arrival}
 
-        hit = dw.store.lookup(key, now=now, full=True)
+        hit = self._lookup(dw, key, now)
         if hit is not None:
             # ---- decode-side prefix hit: the compressed prefix already
             # crossed the wire for an earlier request; fetch it from the
@@ -684,10 +685,13 @@ class ClusterRuntime:
         # The bytes that just crossed the wire seed THIS decode worker's
         # pool tier (no extra transfer): later identical prompts routed
         # here hit it.
-        dw.store.put(key, (comp, first, profile.s_dec), wire_bytes,
-                     kv_bytes=ctx.kv_bytes, workload=req.workload,
-                     slo_class=req.slo_class, now=tr.end,
-                     tier=len(dw.store.tiers) - 1)
+        with tracing.span("store_put") as sp:
+            dw.store.put(key, (comp, first, profile.s_dec), wire_bytes,
+                         kv_bytes=ctx.kv_bytes, workload=req.workload,
+                         slo_class=req.slo_class, now=tr.end,
+                         tier=len(dw.store.tiers) - 1)
+            if sp:
+                sp.add(bytes=wire_bytes)
         end = busy + tr.t_wait + tr.t_comm + t_decompress
         bd.update(prefill=t_prefill, compress=t_compress,
                   wire_wait=tr.t_wait, comm=tr.t_comm,
@@ -702,53 +706,68 @@ class ClusterRuntime:
         dw.occupy(slot, first, prompt=tokens)
         return end, busy
 
+    @staticmethod
+    def _lookup(dw: DecodeWorker, key, now: float) -> Optional[TierHit]:
+        """The decode worker's store lookup of a whole prompt (span
+        ``store_lookup``).  full=True: a partial (block-aligned) prefix
+        hit would leave the uncovered prompt suffix without KV — the
+        runtime has no top-up prefill, so only a full-coverage entry
+        counts as a pool hit."""
+        with tracing.span("store_lookup") as sp:
+            hit = dw.store.lookup(key, now=now, full=True)
+            if sp:
+                sp.add(hit=0 if hit is None else 1,
+                       bytes=0 if hit is None else hit.entry.wire_bytes)
+        return hit
+
     # ------------------------------------------------------------------
     def _finish(self, dw: DecodeWorker, slot: Slot, now: float) -> None:
-        req = slot.req
-        toks = np.asarray(slot.toks, dtype=np.int32)
-        req.ttft = slot.ttft
-        req.done = now
-        req.chosen = slot.profile
-        req.breakdown = slot.breakdown
-        # One SLO metric end to end: the same latency (ttft or jct,
-        # request-pinned or scenario default) is compared to t_slo here
-        # AND fed to the bandit, so its violation cooldown fires on the
-        # metric the runtime reports — not a different one.
-        metric = req.resolved_slo_metric(self.slo_metric_default)
-        observed = (slot.ttft if metric == "ttft"
-                    else sum(slot.breakdown.values()))
-        req.slo_violated = req.t_slo > 0 and observed > req.t_slo
-        if self.controller is not None and slot.decision is not None:
-            # Residual-bandit feedback: the realized critical-path latency
-            # of the SLO metric, landing on the slot's ROUTE bandit (the
-            # Slot.ctx carries the route), so each link's drift is learned
-            # separately.
-            self.controller.observe(slot.ctx, slot.decision, observed)
-        if self.controller is not None and slot.drafts_offered > 0:
-            # Accept-rate feedback for controller-adaptive speculation:
-            # the realized per-draft acceptance on this (workload, route),
-            # feeding the EWMA behind Decision.spec_k (DESIGN.md §15).
-            observe_accept = getattr(self.controller, "observe_accept",
-                                     None)
-            if observe_accept is not None:
-                observe_accept(req.workload, slot.route,
-                               slot.drafts_accepted / slot.drafts_offered)
-        self.completed.append(ServedRequest(
-            rid=req.rid, workload=req.workload, slo_class=req.slo_class,
-            text=self.tok.decode(toks), tokens=toks, profile=slot.profile,
-            pool_hit=slot.pool_hit, kv_bytes=int(req.kv_bytes),
-            wire_bytes=slot.wire_bytes, arrival=req.arrival, done=now,
-            ttft=slot.ttft, slot=slot.idx, route=slot.route,
-            breakdown=slot.breakdown, t_pool_write=slot.pool_write,
-            slo_metric=metric, t_slo=req.t_slo,
-            slo_violated=req.slo_violated, spec_k=slot.spec_k,
-            verify_steps=slot.verify_steps,
-            spec_committed=slot.spec_committed,
-            drafts_offered=slot.drafts_offered,
-            drafts_accepted=slot.drafts_accepted))
-        self.scheduler.finish(req.rid)
-        dw.release(slot)             # returns the local arena slot id
-        self._prompts.pop(req.rid, None)
+        with tracing.span("finish", rid=slot.req.rid):
+            req = slot.req
+            toks = np.asarray(slot.toks, dtype=np.int32)
+            req.ttft = slot.ttft
+            req.done = now
+            req.chosen = slot.profile
+            req.breakdown = slot.breakdown
+            # One SLO metric end to end: the same latency (ttft or jct,
+            # request-pinned or scenario default) is compared to t_slo here
+            # AND fed to the bandit, so its violation cooldown fires on the
+            # metric the runtime reports — not a different one.
+            metric = req.resolved_slo_metric(self.slo_metric_default)
+            observed = (slot.ttft if metric == "ttft"
+                        else sum(slot.breakdown.values()))
+            req.slo_violated = req.t_slo > 0 and observed > req.t_slo
+            if self.controller is not None and slot.decision is not None:
+                # Residual-bandit feedback: the realized critical-path latency
+                # of the SLO metric, landing on the slot's ROUTE bandit (the
+                # Slot.ctx carries the route), so each link's drift is learned
+                # separately.
+                self.controller.observe(slot.ctx, slot.decision, observed)
+            if self.controller is not None and slot.drafts_offered > 0:
+                # Accept-rate feedback for controller-adaptive speculation:
+                # the realized per-draft acceptance on this (workload, route),
+                # feeding the EWMA behind Decision.spec_k (DESIGN.md §15).
+                observe_accept = getattr(self.controller, "observe_accept",
+                                         None)
+                if observe_accept is not None:
+                    observe_accept(req.workload, slot.route,
+                                   slot.drafts_accepted / slot.drafts_offered)
+            self.completed.append(ServedRequest(
+                rid=req.rid, workload=req.workload, slo_class=req.slo_class,
+                text=self.tok.decode(toks), tokens=toks, profile=slot.profile,
+                pool_hit=slot.pool_hit, kv_bytes=int(req.kv_bytes),
+                wire_bytes=slot.wire_bytes, arrival=req.arrival, done=now,
+                ttft=slot.ttft, slot=slot.idx, route=slot.route,
+                breakdown=slot.breakdown, t_pool_write=slot.pool_write,
+                slo_metric=metric, t_slo=req.t_slo,
+                slo_violated=req.slo_violated, spec_k=slot.spec_k,
+                verify_steps=slot.verify_steps,
+                spec_committed=slot.spec_committed,
+                drafts_offered=slot.drafts_offered,
+                drafts_accepted=slot.drafts_accepted))
+            self.scheduler.finish(req.rid)
+            dw.release(slot)             # returns the local arena slot id
+            self._prompts.pop(req.rid, None)
 
     # ------------------------------------------------------------------
     def _admit_and_start(self, now: float) -> List[Tuple[Slot, float]]:
@@ -769,17 +788,20 @@ class ClusterRuntime:
             if not eligible:
                 break
             req = self.scheduler.admit(now)
-            route = self.router.choose(req, eligible, now, self)
+            with tracing.span("route", rid=req.rid):
+                route = self.router.choose(req, eligible, now, self)
             admitted[route.prefill.wid] += 1
             req.route = route.name
             req.slot = route.decode.free_slots.pop()
             pwid = route.prefill.wid
-            if self.cfg.mode == "pd":
-                end, self._step_busy[pwid] = self._start_request_pd(
-                    req, route, now, self._step_busy[pwid])
-            else:
-                end, self._step_busy[pwid] = self._start_request(
-                    req, route, now, self._step_busy[pwid])
+            # The request's start-of-life stages carry its rid.
+            with tracing.span("start", rid=req.rid):
+                if self.cfg.mode == "pd":
+                    end, self._step_busy[pwid] = self._start_request_pd(
+                        req, route, now, self._step_busy[pwid])
+                else:
+                    end, self._step_busy[pwid] = self._start_request(
+                        req, route, now, self._step_busy[pwid])
             started.append((route.decode.slots[req.rid], end))
         return started
 
@@ -789,54 +811,55 @@ class ClusterRuntime:
         worker advances its previously-running slots one token (one
         masked batched decode per worker).  The iteration costs ``max``
         over all streams; the difference is charged as stall."""
-        now = self.clock
-        started = self._admit_and_start(now)
-        prefill_cost = max((end for _, end in started), default=0.0)
-        new_rids = {s.req.rid for s, _ in started}
+        with tracing.span("step"):
+            now = self.clock
+            started = self._admit_and_start(now)
+            prefill_cost = max((end for _, end in started), default=0.0)
+            new_rids = {s.req.rid for s, _ in started}
 
-        # Decode streams: each worker one masked jitted arena call.
-        decode_streams: List[Tuple[float, List[Slot]]] = []
-        active_total = 0
-        for dw in self.decode_workers:
-            active = [s for rid, s in dw.slots.items()
-                      if rid not in new_rids]
-            if not active:
-                continue
-            wall = dw.decode_iteration(active)
-            cost = (1.0 / self.cfg.decode_tok_s
-                    if self.cfg.decode_tok_s else wall)
-            decode_streams.append((cost, active))
-            active_total += len(active)
+            # Decode streams: each worker one masked jitted arena call.
+            decode_streams: List[Tuple[float, List[Slot]]] = []
+            active_total = 0
+            for dw in self.decode_workers:
+                active = [s for rid, s in dw.slots.items()
+                          if rid not in new_rids]
+                if not active:
+                    continue
+                wall = dw.decode_iteration(active)
+                cost = (1.0 / self.cfg.decode_tok_s
+                        if self.cfg.decode_tok_s else wall)
+                decode_streams.append((cost, active))
+                active_total += len(active)
 
-        # The iteration costs the slowest stream (PD-separated workers run
-        # concurrently); the difference is charged to each slot as
-        # "stall" so breakdowns sum exactly to jct.
-        iter_cost = max([prefill_cost]
-                        + [cost for cost, _ in decode_streams])
-        for cost, active in decode_streams:
-            for slot in active:
-                slot.breakdown["decode"] = \
-                    slot.breakdown.get("decode", 0.0) + cost
+            # The iteration costs the slowest stream (PD-separated workers run
+            # concurrently); the difference is charged to each slot as
+            # "stall" so breakdowns sum exactly to jct.
+            iter_cost = max([prefill_cost]
+                            + [cost for cost, _ in decode_streams])
+            for cost, active in decode_streams:
+                for slot in active:
+                    slot.breakdown["decode"] = \
+                        slot.breakdown.get("decode", 0.0) + cost
+                    slot.breakdown["stall"] = \
+                        slot.breakdown.get("stall", 0.0) + iter_cost - cost
+            for slot, end_offset in started:
                 slot.breakdown["stall"] = \
-                    slot.breakdown.get("stall", 0.0) + iter_cost - cost
-        for slot, end_offset in started:
-            slot.breakdown["stall"] = \
-                slot.breakdown.get("stall", 0.0) + iter_cost - end_offset
-        self.clock = now + iter_cost
-        self.steps += 1
-        for dw in self.decode_workers:
-            for slot in list(dw.slots.values()):
-                if len(slot.toks) > slot.req.out_tokens:
-                    self._finish(dw, slot, self.clock)
+                    slot.breakdown.get("stall", 0.0) + iter_cost - end_offset
+            self.clock = now + iter_cost
+            self.steps += 1
+            for dw in self.decode_workers:
+                for slot in list(dw.slots.values()):
+                    if len(slot.toks) > slot.req.out_tokens:
+                        self._finish(dw, slot, self.clock)
 
-        stats = {"step": float(self.steps), "clock": self.clock,
-                 "in_flight": float(active_total + len(started)),
-                 "queue_depth": float(self.scheduler.queue_depth),
-                 "completed": float(len(self.completed)),
-                 "store_used": float(sum(t.store.used_bytes
-                                         for t in self._distinct_tiers()))}
-        self.step_log.append(stats)
-        return stats
+            stats = {"step": float(self.steps), "clock": self.clock,
+                     "in_flight": float(active_total + len(started)),
+                     "queue_depth": float(self.scheduler.queue_depth),
+                     "completed": float(len(self.completed)),
+                     "store_used": float(sum(t.store.used_bytes
+                                             for t in self._distinct_tiers()))}
+            self.step_log.append(stats)
+            return stats
 
     # ------------------------------------------------------------------
     def run(self, max_steps: int = 10_000) -> List[ServedRequest]:

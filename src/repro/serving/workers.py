@@ -32,7 +32,6 @@ existing imports keep working.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -57,6 +56,7 @@ from repro.core.quality import (
     inject_quant_pages,
 )
 from repro.core.strategy import StrategyConfig, paged_eligible
+from repro.serving import tracing
 from repro.serving.kvstore import TierSpec
 from repro.serving.request import Request
 
@@ -83,19 +83,22 @@ def compress_kvs(strategy: StrategyConfig, kvs: Sequence[Any]
     """Compress each KV prefix for the wire.  Returns
     ``(payloads, wire_bytes, measured_seconds)``."""
     pipe = CompressionPipeline(strategy)
-    t0 = time.perf_counter()
-    comps = [pipe.compress(kv) for kv in kvs]
-    t_wall = time.perf_counter() - t0
-    return comps, sum(c.total_bytes() for c in comps), t_wall
+    with tracing.timed("compress") as sp:
+        comps = [pipe.compress(kv) for kv in kvs]
+        if sp:
+            sp.add(kv_bytes=sum(kv.nbytes_wire() for kv in kvs),
+                   wire_bytes=sum(c.total_bytes() for c in comps))
+    return comps, sum(c.total_bytes() for c in comps), sp.seconds
 
 
 def decompress_kvs(comps: Sequence[CompressedKV]
                    ) -> Tuple[List[Any], float]:
     """Restore wire payloads to KV.  Returns ``(kvs, measured_seconds)``."""
-    t0 = time.perf_counter()
-    kvs = [CompressionPipeline(c.strategy).decompress(c) for c in comps]
-    t_wall = time.perf_counter() - t0
-    return kvs, t_wall
+    with tracing.timed("restore") as sp:
+        kvs = [CompressionPipeline(c.strategy).decompress(c) for c in comps]
+        if sp:
+            sp.add(bytes=sum(kv.k.nbytes + kv.v.nbytes for kv in kvs))
+    return kvs, sp.seconds
 
 
 def quant_entry_arrays(comp: CompressedKV):
@@ -386,8 +389,6 @@ class PrefillWorker(_Placed):
         self.cfg = cfg
         self.controller = controller
         self.static_profile = static_profile
-        self.prefills = 0             # lifetime prefill count
-        self.busy_seconds = 0.0       # lifetime prefill-stream occupancy
         # EWMA of measured prefill wall-clock: the router's t_model
         # estimate when no virtual clock is configured.
         self._ewma_prefill: Optional[float] = None
@@ -412,15 +413,15 @@ class PrefillWorker(_Placed):
         """Real batch-1 prefill.  Returns ``(caches, first_token,
         t_prefill)`` with ``t_prefill`` under the configured cost model."""
         pre1 = self._prefill_fn()
-        t0 = time.perf_counter()
-        logits, caches = pre1(self.params, {"tokens": tokens[None, :]})
-        # lint: sync-ok(measures real prefill wall-clock for the EWMA model)
-        jax.block_until_ready(logits)
-        t_wall = time.perf_counter() - t0
+        with tracing.timed("prefill", rid=req.rid) as sp:
+            logits, caches = pre1(self.params, {"tokens": tokens[None, :]})
+            # lint: sync-ok(measures real prefill wall-clock for the EWMA model)
+            jax.block_until_ready(logits)
+            if sp:
+                sp.add(tokens=len(tokens))
+        t_wall = sp.seconds
         t_prefill = (req.ctx_tokens / self.cfg.prefill_tok_s
                      if self.cfg.prefill_tok_s else t_wall)
-        self.prefills += 1
-        self.busy_seconds += t_prefill
         self._ewma_prefill = t_wall if self._ewma_prefill is None \
             else 0.7 * self._ewma_prefill + 0.3 * t_wall
         # lint: sync-ok(one first-token pull per prefill seeds the decode slot)
@@ -449,8 +450,11 @@ class PrefillWorker(_Placed):
             kv_bytes=kv.nbytes_wire(),
             slo_metric=req.resolved_slo_metric(slo_default),
             route=route, decode_time=t_decode)
-        profile, decision = _select_profile(self.controller,
-                                            self.static_profile, ctx)
+        with tracing.span("select") as sp:
+            profile, decision = _select_profile(self.controller,
+                                                self.static_profile, ctx)
+            if sp:
+                sp.add(profile=profile.strategy.short_name())
         comps, _, t_wall = compress_kvs(profile.strategy, [kv])
         t_compress = codec_cost(self.cfg, t_wall, kv.nbytes_wire(),
                                 profile.s_enc)
@@ -567,31 +571,41 @@ class DecodeWorker(_Placed):
     def copy_from_caches(self, caches, idx: int) -> None:
         """Materialize arena row ``idx`` from a prefill worker's batch-1
         cache (the cold path's slot hand-off; the cache moves from the
-        prefill worker's device to this one by ``device_put``)."""
-        self.ensure_arena()
-        caches = self._put(caches)
-        if self.cfg.paged:
-            self.page_table.ensure(idx, self.cfg.seq)
-            row = self.page_table.block_row(idx, self._pps)
-            self._arena = copy_cache_slot_paged(
-                self.model.cfg, self._arena, caches, row,
-                self.cfg.page_size)
-            self._quant_len[idx] = 0
-            return
-        self._arena = copy_cache_slot(self.model.cfg, self._arena,
-                                      caches, idx)
+        prefill worker's device to this one by ``device_put``).  No byte
+        crosses from the host."""
+        with tracing.span("inject") as sp:
+            self.ensure_arena()
+            caches = self._put(caches)
+            if self.cfg.paged:
+                self.page_table.ensure(idx, self.cfg.seq)
+                row = self.page_table.block_row(idx, self._pps)
+                self._arena = copy_cache_slot_paged(
+                    self.model.cfg, self._arena, caches, row,
+                    self.cfg.page_size)
+                self._quant_len[idx] = 0
+            else:
+                self._arena = copy_cache_slot(self.model.cfg, self._arena,
+                                              caches, idx)
+            if sp:
+                sp.add(bytes=0)
 
     def inject_restored(self, kv, idx: int) -> None:
-        """Materialize arena row ``idx`` from a wire-restored KV."""
-        self.ensure_arena()
-        if self.cfg.paged:
-            self.page_table.ensure(idx, kv.seq)
-            row = self.page_table.block_row(idx, self._pps)
-            self._arena = inject_kv_paged(self.model.cfg, self._arena,
-                                          row, kv, self.cfg.page_size)
-            self._quant_len[idx] = 0
-            return
-        self._arena = inject_kv(self.model.cfg, self._arena, idx, kv)
+        """Materialize arena row ``idx`` from a wire-restored KV (pushed
+        from the host in the arena's dtype)."""
+        with tracing.span("inject") as sp:
+            self.ensure_arena()
+            if self.cfg.paged:
+                self.page_table.ensure(idx, kv.seq)
+                row = self.page_table.block_row(idx, self._pps)
+                self._arena = inject_kv_paged(self.model.cfg, self._arena,
+                                              row, kv, self.cfg.page_size)
+                self._quant_len[idx] = 0
+            else:
+                self._arena = inject_kv(self.model.cfg, self._arena, idx,
+                                        kv)
+            if sp:
+                leaf = jax.tree_util.tree_leaves(self._arena)[0]
+                sp.add(bytes=(kv.k.size + kv.v.size) * leaf.dtype.itemsize)
 
     def fetch_entry(self, entry, idx: int) -> Tuple[int, float]:
         """Land a stored pool entry in arena slot ``idx``.  Returns
@@ -610,18 +624,19 @@ class DecodeWorker(_Placed):
         comp, first, s_dec = entry.payload
         if (self.cfg.paged and isinstance(comp, CompressedKV)
                 and paged_eligible(comp.strategy, head_dim=comp.shape[3])):
-            t0 = time.perf_counter()
-            (kc, ks), (vc, vs) = quant_entry_arrays(comp)
-            self.ensure_arena()
-            seq = comp.shape[2]
-            self.page_table.ensure(idx, seq)
-            row = self.page_table.block_row(idx, self._pps)
-            self._qcodes, self._qscales = inject_quant_pages(
-                self.model.cfg, self._qcodes, self._qscales, row,
-                kc, ks, vc, vs, seq, self.cfg.page_size)
-            self._quant_len[idx] = seq
-            t_wall = time.perf_counter() - t0
-            return int(first), codec_cost(self.cfg, t_wall,
+            with tracing.timed("restore") as sp:
+                (kc, ks), (vc, vs) = quant_entry_arrays(comp)
+                self.ensure_arena()
+                seq = comp.shape[2]
+                self.page_table.ensure(idx, seq)
+                row = self.page_table.block_row(idx, self._pps)
+                self._qcodes, self._qscales = inject_quant_pages(
+                    self.model.cfg, self._qcodes, self._qscales, row,
+                    kc, ks, vc, vs, seq, self.cfg.page_size)
+                self._quant_len[idx] = seq
+                if sp:
+                    sp.add(bytes=kc.nbytes + ks.nbytes + vc.nbytes + vs.nbytes)
+            return int(first), codec_cost(self.cfg, sp.seconds,
                                           entry.kv_bytes, float("inf"))
         restored, t_wall = decompress_kvs([comp])
         t_decompress = codec_cost(self.cfg, t_wall, entry.kv_bytes, s_dec)
@@ -704,22 +719,24 @@ class DecodeWorker(_Placed):
             for slot in active:
                 self.page_table.ensure(slot.idx,
                                        int(self._positions[slot.idx]) + 1)
-            t0 = time.perf_counter()
-            nxt, self._arena = dec(
-                self.params, self._arena, self._qcodes,
-                self._qscales, jnp.asarray(self._block_tables()),
-                jnp.asarray(self._quant_len),
-                jnp.asarray(self._last_tok[:, None]),
-                jnp.asarray(self._positions), jnp.asarray(mask))
-        else:
-            t0 = time.perf_counter()
-            nxt, self._arena = dec(
-                self.params, self._arena,
-                jnp.asarray(self._last_tok[:, None]),
-                jnp.asarray(self._positions), jnp.asarray(mask))
-        # lint: sync-ok(the step's single sanctioned sync - one batched pull)
-        nxt = np.asarray(nxt)
-        wall = time.perf_counter() - t0
+        with tracing.timed("decode_step") as sp:
+            if self.cfg.paged:
+                nxt, self._arena = dec(
+                    self.params, self._arena, self._qcodes,
+                    self._qscales, jnp.asarray(self._block_tables()),
+                    jnp.asarray(self._quant_len),
+                    jnp.asarray(self._last_tok[:, None]),
+                    jnp.asarray(self._positions), jnp.asarray(mask))
+            else:
+                nxt, self._arena = dec(
+                    self.params, self._arena,
+                    jnp.asarray(self._last_tok[:, None]),
+                    jnp.asarray(self._positions), jnp.asarray(mask))
+            with tracing.span("token_pull"):
+                # lint: sync-ok(the step's single sanctioned sync - one batched pull)
+                nxt = np.asarray(nxt)
+            if sp:
+                sp.add(live=len(active))
         for slot in active:
             t = int(nxt[slot.idx])
             slot.toks.append(t)
@@ -728,7 +745,7 @@ class DecodeWorker(_Placed):
             if slot.spec_k > 0 and self._draft is not None:
                 self._draft.commit(slot.idx, slot.req.rid, [t])
         self.decode_steps += 1
-        return wall
+        return sp.seconds
 
     def _verify_iteration(self, active: List[Slot],
                           proposals: Dict[int, List[int]]) -> float:
@@ -755,20 +772,22 @@ class DecodeWorker(_Placed):
                 need = (int(self._positions[slot.idx]) + 1
                         + len(proposals.get(slot.idx, [])))
                 self.page_table.ensure(slot.idx, need)
-            t0 = time.perf_counter()
-            out, self._arena = fn(
-                self.params, self._arena, self._qcodes,
-                self._qscales, jnp.asarray(self._block_tables()),
-                jnp.asarray(self._quant_len), jnp.asarray(toks),
-                jnp.asarray(self._positions), jnp.asarray(mask))
-        else:
-            t0 = time.perf_counter()
-            out, self._arena = fn(
-                self.params, self._arena, jnp.asarray(toks),
-                jnp.asarray(self._positions), jnp.asarray(mask))
-        # lint: sync-ok(the step's single sanctioned sync - one batched pull)
-        out = np.asarray(out)
-        wall = time.perf_counter() - t0
+        with tracing.timed("decode_step") as sp:
+            if self.cfg.paged:
+                out, self._arena = fn(
+                    self.params, self._arena, self._qcodes,
+                    self._qscales, jnp.asarray(self._block_tables()),
+                    jnp.asarray(self._quant_len), jnp.asarray(toks),
+                    jnp.asarray(self._positions), jnp.asarray(mask))
+            else:
+                out, self._arena = fn(
+                    self.params, self._arena, jnp.asarray(toks),
+                    jnp.asarray(self._positions), jnp.asarray(mask))
+            with tracing.span("token_pull"):
+                # lint: sync-ok(the step's single sanctioned sync - one batched pull)
+                out = np.asarray(out)
+            if sp:
+                sp.add(live=len(active))
         for slot in active:
             drafts = proposals.get(slot.idx, [])
             row = out[slot.idx]
@@ -791,4 +810,4 @@ class DecodeWorker(_Placed):
                     slot.idx, int(self._positions[slot.idx]))
         self.decode_steps += 1
         self.verify_steps += 1
-        return wall
+        return sp.seconds

@@ -1,4 +1,3 @@
-from repro.utils.timing import Timer, timed
 from repro.utils.trees import tree_bytes, tree_size
 
-__all__ = ["Timer", "timed", "tree_bytes", "tree_size"]
+__all__ = ["tree_bytes", "tree_size"]

@@ -252,21 +252,43 @@ def _jitter_mult(seed, start, nbytes):
     return 1.0 + 0.05 * rng.standard_normal()
 """
 
+# The serving path's span recorder, as shipped, under a hot root that
+# times its decode step through it: clean under every rule.
+TRACING_SRC = (REPO / "src" / "repro" / "serving" / "tracing.py").read_text()
+TRACED_STEP = """\
+import jax.numpy as jnp
+
+from repro.serving import tracing
+
+
+def step(batch):
+    with tracing.span("step"):
+        with tracing.timed("decode_step") as sp:
+            logits = jnp.ones((4, 8))
+        if sp:
+            sp.add(live=4)
+    return logits, sp.seconds
+"""
+TRACED = {"serving/tracing.py": TRACING_SRC, "serving/engine.py": TRACED_STEP}
+
 FIXTURES = {
     "host-sync": [
         (True, {"serving/engine.py": SYNC_BAD}),
         (False, {"serving/engine.py": SYNC_CLEAN}),
         (False, {"serving/engine.py": SYNC_LAUNDERED}),
+        (False, TRACED),
     ],
     "clock-accounting": [
         (True, {"serving/billing.py": CLOCK_DEAD_T}),
         (True, {"serving/billing.py": CLOCK_DOUBLE}),
         (True, {"serving/wire.py": CLOCK_BACKWARDS}),
         (False, {"serving/runtime.py": CLOCK_CLEAN}),
+        (False, TRACED),
     ],
     "units": [
         (True, {"serving/route.py": UNITS_MIX}),
         (False, {"serving/route.py": UNITS_CLEAN}),
+        (False, TRACED),
     ],
     "kernel-contract": [
         (True, {"src/pkg/kernels/__init__.py": KC_INIT_BAD,
@@ -277,14 +299,17 @@ FIXTURES = {
                  "src/pkg/kernels/ref.py": KC_REF_OK,
                  "src/pkg/kernels/ops.py": KC_OPS_OK,
                  "tests/test_foo.py": KC_TEST_OK}),
+        (False, TRACED),
     ],
     "ownership": [
         (True, {"serving/cluster.py": OWN_BAD}),
         (False, {"serving/cluster.py": OWN_CLEAN}),
+        (False, TRACED),
     ],
     "determinism": [
         (True, {"serving/simulator.py": DET_BAD}),
         (False, {"serving/simulator.py": DET_CLEAN}),
+        (False, TRACED),
     ],
 }
 
