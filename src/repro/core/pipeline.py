@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Tuple
+from typing import TYPE_CHECKING, Any, Dict, List, Optional, Tuple, Union
 
 import numpy as np
 
@@ -29,6 +29,9 @@ from repro.core.quantizers import (
 )
 from repro.core.strategy import SOURCE_BYTES, StrategyConfig, is_identity
 from repro.core.transforms import apply_transform, invert_transform, transform_meta_bytes
+
+if TYPE_CHECKING:
+    from repro.core.quality import DeviceKV
 
 HEADER_BYTES = 64  # fixed per-message framing overhead
 
@@ -141,14 +144,23 @@ class CompressionPipeline:
         self.head_scores = head_scores
 
     # ------------------------------------------------------------------
-    def compress(self, kv: KVCache) -> CompressedKV:
+    def compress(self, kv: Union[KVCache, "DeviceKV"]) -> CompressedKV:
+        """``kv`` is a host :class:`KVCache`, or a prompt's KV still on the
+        device (:class:`repro.core.quality.DeviceKV`): the identity payload
+        is then cast to fp16 on the device and pulled as is, and every
+        other strategy runs on the float32 KV pulled to the host."""
         cfg = self.strategy
         if is_identity(cfg):
-            payload = np.concatenate(
-                [kv.k.ravel(), kv.v.ravel()]
-            ).astype(np.float16).tobytes()
+            if isinstance(kv, KVCache):
+                payload = np.concatenate(
+                    [kv.k.ravel(), kv.v.ravel()]
+                ).astype(np.float16).tobytes()
+            else:
+                payload = kv.fp16().tobytes()
             return CompressedKV(cfg, kv.shape, [], [], {"kind": "none"},
                                 {"kind": "none"}, identity_payload=payload)
+        if not isinstance(kv, KVCache):
+            kv = kv.host()
 
         k_t, k_ctx = apply_transform(cfg.transform, kv.k, cfg.delta_group)
         v_t, v_ctx = apply_transform(cfg.transform, kv.v, cfg.delta_group)

@@ -10,7 +10,7 @@ compressibility and accuracy rankings differ per workload (Motivation 1).
 from __future__ import annotations
 
 import os
-from functools import lru_cache
+from functools import lru_cache, partial
 from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -21,7 +21,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core.kvcache import KVCache
 from repro.core.pipeline import CompressionPipeline
-from repro.core.strategy import StrategyConfig, is_identity
+from repro.core.strategy import SOURCE_BYTES, StrategyConfig, is_identity
 from repro.data.synthetic import WORKLOADS, make_batch, make_prompt
 from repro.data.tokenizer import ByteTokenizer
 
@@ -84,38 +84,82 @@ def get_reference_model(steps: int = REF_STEPS, seed: int = 0):
 # ---------------------------------------------------------------------------
 # Cache <-> KVCache conversion (attention layers, dense stacks)
 # ---------------------------------------------------------------------------
-def extract_kv(cfg, caches, batch_idx: int, upto: int) -> KVCache:
-    """Pull one batch element's attention KV as (L, H, S, D) numpy: one
-    device->host pull per layer and tensor (span ``kv_pull``)."""
-    from repro.models.transformer import plan_stack
-    from repro.serving import tracing
+@partial(jax.jit, static_argnames=("upto", "dtype"))
+def _gather_kv(prefix, blocks, batch_idx, upto: int, dtype):
+    """One batch element's attention K and V as ONE (2, L, H, S, D) array
+    in ``dtype``: K then V, the ``prefix`` layers first, then the scanned
+    ``blocks`` block by block (layer order of the stack)."""
+    out = []
+    for t in ("k", "v"):
+        # prefix leaves (B, S_max, H, D); block leaves (n_blocks, B, ...)
+        layers = [jax.lax.dynamic_index_in_dim(c[t], batch_idx, 0,
+                                               False)[None, :upto]
+                  for c in prefix]
+        if blocks:
+            per = jnp.stack([jax.lax.dynamic_index_in_dim(
+                c[t], batch_idx, 1, False)[:, :upto] for c in blocks], 1)
+            layers.append(per.reshape(-1, *per.shape[2:]))
+        out.append(jnp.concatenate(layers).swapaxes(1, 2).astype(dtype))
+    return jnp.stack(out)
 
-    plan = plan_stack(cfg)
-    ks: List[np.ndarray] = []
-    vs: List[np.ndarray] = []
-    with tracing.span("kv_pull") as sp:
-        for i, spec in enumerate(plan.prefix_specs):
-            if spec.kind != "attn":
-                continue
-            c = caches["prefix"][f"layer{i}"]
-            ks.append(np.asarray(c["k"][batch_idx, :upto],
-                                 np.float32).transpose(1, 0, 2))
-            vs.append(np.asarray(c["v"][batch_idx, :upto],
-                                 np.float32).transpose(1, 0, 2))
-        for blk in range(plan.n_blocks):
-            for j, spec in enumerate(plan.period_specs):
-                if spec.kind != "attn":
-                    continue
-                c = caches["blocks"][f"layer{j}"]
-                ks.append(np.asarray(c["k"][blk, batch_idx, :upto],
-                                     np.float32).transpose(1, 0, 2))
-                vs.append(np.asarray(c["v"][blk, batch_idx, :upto],
-                                     np.float32).transpose(1, 0, 2))
-        kv = KVCache(np.stack(ks), np.stack(vs))
-        if sp:
-            sp.add(bytes=kv.k.nbytes + kv.v.nbytes,
-                   transfers=len(ks) + len(vs))
-    return kv
+
+class DeviceKV:
+    """One batch element's attention KV, still in the device cache pytree
+    that a prefill wrote: the source a :class:`CompressionPipeline` pulls
+    from.  Its shape and wire size are known without a pull; each pull is
+    one jitted gather and ONE device->host transfer (span ``kv_pull``:
+    ``bytes`` that crossed, ``transfers``, and ``device_cast`` 1 where the
+    device made the wire payload, 0 where the host widens)."""
+
+    def __init__(self, cfg, caches, batch_idx: int, upto: int):
+        from repro.models.transformer import plan_stack
+
+        plan = plan_stack(cfg)
+        self._prefix = tuple(caches["prefix"][f"layer{i}"]
+                             for i, s in enumerate(plan.prefix_specs)
+                             if s.kind == "attn")
+        self._blocks = tuple(caches["blocks"][f"layer{j}"]
+                             for j, s in enumerate(plan.period_specs)
+                             if s.kind == "attn")
+        self._batch_idx = batch_idx
+        self._upto = upto
+        leaf = (self._prefix + self._blocks)[0]["k"]
+        n_layers = len(self._prefix) + len(self._blocks) * plan.n_blocks
+        self.shape = (n_layers, leaf.shape[-2], upto, leaf.shape[-1])
+        self.cache_dtype = np.dtype(leaf.dtype)
+
+    def nbytes_wire(self) -> int:
+        """Bytes of the uncompressed payload on the wire (logical bf16),
+        as :meth:`KVCache.nbytes_wire`."""
+        return 2 * int(np.prod(self.shape)) * SOURCE_BYTES
+
+    def _pull(self, dtype, device_cast: int) -> np.ndarray:
+        from repro.serving import tracing
+
+        with tracing.span("kv_pull") as sp:
+            arr = np.asarray(_gather_kv(self._prefix, self._blocks,
+                                        self._batch_idx, self._upto,
+                                        np.dtype(dtype)))
+            if sp:
+                sp.add(bytes=arr.nbytes, transfers=1, device_cast=device_cast)
+        return arr
+
+    def fp16(self) -> np.ndarray:
+        """K then V as one C-ordered (2, L, H, S, D) float16 host array,
+        cast on the device: the identity hand-off's wire payload."""
+        return self._pull(np.float16, device_cast=1)
+
+    def host(self) -> KVCache:
+        """The float32 :class:`KVCache`: pulled in the cache dtype and
+        widened on the host (exact from bf16)."""
+        kv = self._pull(self.cache_dtype, device_cast=0).astype(np.float32)
+        return KVCache(kv[0], kv[1])
+
+
+def extract_kv(cfg, caches, batch_idx: int, upto: int) -> KVCache:
+    """Pull one batch element's attention KV as (L, H, S, D) float32
+    numpy, in one device->host transfer (span ``kv_pull``)."""
+    return DeviceKV(cfg, caches, batch_idx, upto).host()
 
 
 def copy_cache_slot(cfg, dst, src, slot, src_idx: int = 0):

@@ -45,11 +45,11 @@ from repro.core.kvcache import PageTable
 from repro.core.pipeline import CompressedKV, CompressionPipeline
 from repro.core.profiles import Profile
 from repro.core.quality import (
+    DeviceKV,
     _jitted_steps,
     _paged_steps,
     copy_cache_slot,
     copy_cache_slot_paged,
-    extract_kv,
     init_paged_pools,
     inject_kv,
     inject_kv_paged,
@@ -80,7 +80,8 @@ def _select_profile(controller: Optional[ServiceAwareController],
 # ---------------------------------------------------------------------------
 def compress_kvs(strategy: StrategyConfig, kvs: Sequence[Any]
                  ) -> Tuple[List[Any], int, float]:
-    """Compress each KV prefix for the wire.  Returns
+    """Compress each KV prefix for the wire: host :class:`KVCache`s, or
+    :class:`DeviceKV`s pulled off the device inside this stage.  Returns
     ``(payloads, wire_bytes, measured_seconds)``."""
     pipe = CompressionPipeline(strategy)
     with tracing.timed("compress") as sp:
@@ -436,8 +437,11 @@ class PrefillWorker(_Placed):
         ``bandwidth`` is the selecting route's goodput estimate (per-link
         in a cluster) and ``route`` its identity, so the controller's
         residual bandit learns each link's drift separately.  Returns
-        ``(comp, ctx, decision, profile, t_compress)``."""
-        kv = extract_kv(self.model.cfg, caches, 0, upto=self.cfg.seq)
+        ``(comp, ctx, decision, profile, t_compress)``.  The profile is
+        chosen from the KV's shape alone; the KV leaves the device inside
+        the compress stage, in the form the strategy needs (the identity
+        hand-off pulls fp16 made on the device)."""
+        kv = DeviceKV(self.model.cfg, caches, 0, upto=self.cfg.seq)
         # Serial decode-stream time under the virtual clock feeds the
         # controller's speculation-length choice (DESIGN.md §15); 0 when
         # wall-clock-measured (the k-selection then ranks on modelled

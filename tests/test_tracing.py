@@ -92,10 +92,15 @@ def test_kv_pull_counts_host_bytes_and_transfers(traced_run):
     rt, _, spans = traced_run
     m = rt.model_cfg
     pulls = [s for s in spans if s.name == "kv_pull"]
-    host = 2 * m.num_layers * m.kv_heads * 64 * m.resolved_head_dim * 4
+    # the lossy profile takes the KV in the cache dtype (bf16), in one
+    # transfer, inside the cluster clock's compress stage
+    pulled = 2 * m.num_layers * m.kv_heads * 64 * m.resolved_head_dim * 2
+    assert len(pulls) == 3
     for s in pulls:
-        assert s.counters["bytes"] == host
-        assert s.counters["transfers"] == 2 * m.num_layers
+        assert s.counters["bytes"] == pulled
+        assert s.counters["transfers"] == 1
+        assert s.counters["device_cast"] == 0
+        assert s.parent.name == "compress"
 
 
 @pytest.mark.slow
@@ -119,8 +124,54 @@ def test_kv_pull_bytes_match_the_extracted_cache(reference_model, tmp_path):
     (sp,) = tracing.SPANS
     tracing.SPANS.clear()
     assert sp.name == "kv_pull" and sp.parent is None and sp.rid is None
-    assert sp.counters["bytes"] == kv.k.nbytes + kv.v.nbytes
-    assert sp.counters["transfers"] == 2 * kv.num_layers
+    # bytes that crossed from the device: the cache's bf16, widened to the
+    # float32 KVCache on the host
+    assert sp.counters["bytes"] == (kv.k.size + kv.v.size) * 2
+    assert sp.counters["transfers"] == 1
+    assert sp.counters["device_cast"] == 0
+
+
+@pytest.mark.parametrize("strategy", ["identity", "uniform8"])
+def test_kv_pull_nests_inside_compress(reference_model, tmp_path, strategy):
+    """The profile is chosen before the pull; the pull is charged to the
+    compress stage, and on the identity path the device made the fp16
+    wire payload that crossed."""
+    from repro.core.profiles import IDENTITY_PROFILE
+    from repro.serving.request import Request
+    from repro.serving.workers import ModelHandle, PrefillWorker, RuntimeConfig
+
+    cfg, params = reference_model
+    profile = IDENTITY_PROFILE if strategy == "identity" else Profile(
+        StrategyConfig(quantizer="uniform", key_bits=8, value_bits=8,
+                       granularity="per_channel"),
+        cr=2.0, s_enc=5e8, s_dec=5e8)
+    pw = PrefillWorker(0, ModelHandle(cfg, params),
+                       RuntimeConfig(seq=32, decode_tokens=4, mode="pd"),
+                       static_profile=profile)
+    req = Request(rid=5, workload="qalike", arrival=0.0, ctx_tokens=32,
+                  out_tokens=4, kv_bytes=0.0)
+    toks = jnp.zeros(32, jnp.int32)
+    caches, _, _ = pw.prefill(req, toks)
+    tracing.SPANS.clear()
+    with jax.profiler.trace(str(tmp_path)):
+        with tracing.span("start", rid=req.rid):
+            comp, ctx, _, _, t_compress = pw.select_and_compress(
+                req, caches, 0.0, bandwidth=1e9, slo_default="ttft")
+    spans = {s.name: s for s in tracing.SPANS}
+    tracing.SPANS.clear()
+    assert sorted(spans) == ["compress", "kv_pull", "select", "start"]
+    pull, comp_sp, select = spans["kv_pull"], spans["compress"], spans["select"]
+    assert pull.parent is comp_sp and select.parent is spans["start"]
+    assert select.t1 <= comp_sp.t0 <= pull.t0 <= pull.t1 <= comp_sp.t1
+    assert pull.rid == req.rid
+    assert pull.counters["transfers"] == 1
+    assert pull.counters["bytes"] == ctx.kv_bytes
+    assert t_compress == comp_sp.seconds
+    if strategy == "identity":
+        assert pull.counters["device_cast"] == 1
+        assert len(comp.identity_payload) == pull.counters["bytes"]
+    else:
+        assert pull.counters["device_cast"] == 0
 
 
 def test_compiles_land_on_the_innermost_span(tmp_path):
