@@ -7,7 +7,10 @@ One process: draw the cell's bf16 weights on the chip from ``--seed``,
 build a ``ClusterRuntime`` with the cell's traffic mix, warm up every
 shape the traffic uses, then offer the mix's open-loop arrivals for
 ``--seconds`` wall seconds, drain the requests that fell due in the window,
-and check a seeded sample of them against the plain float32 reference.
+and check a seeded sample of them against the plain float32 reference of
+the architecture that the configuration file names (``"harness"``: a
+module under ``bench/archs/``, which also gives the counts and the size
+check).
 The last line of standard output is one JSON object (``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, and with ``--trace 1``
 ``breakdown``); its last key, ``checked``, and the last lines of standard
@@ -49,6 +52,9 @@ ROOT = BENCH.parent
 sys.path.insert(0, str(BENCH))
 
 import traffic  # noqa: E402  (bench/traffic.py)
+from archs import CONTRACT  # noqa: E402  (bench/archs/__init__.py)
+
+ARCHS = BENCH / "archs"
 
 WARM_PROMPT_SEED = 424242
 DRAIN_LIMIT_S = 150.0
@@ -80,6 +86,7 @@ class Cell:
         configs = {c["name"]: c for c in self.spec["configs"]}
         self.config_entry = configs[self.workload["config"]]
         self.config = json.loads((ROOT / self.config_entry["file"]).read_text())
+        self.harness = load_harness(self.config, self.config_entry["file"])
         self.mix = json.loads(
             (BENCH / "traffic" / f"{self.workload['traffic']}.json").read_text())
         if self.mix["mode"] != "pd":
@@ -101,6 +108,26 @@ class Cell:
     def end_to_end(self) -> List[Dict]:
         return [m for m in self.spec["end_to_end"]
                 if self.name in m.get("workloads", [self.name])]
+
+
+def load_harness(config: Dict, config_file: str, where: Path = ARCHS):
+    """The architecture module ``<where>/<harness>.py`` that the
+    configuration file names: its reference, counts and size check (the
+    contract is in ``bench/archs/__init__.py``)."""
+    name = config.get("harness")
+    if not name:
+        fail(f"{config_file} names no \"harness\" (a module under "
+             f"{ARCHS.relative_to(ROOT)}/)")
+    path = where / f"{name}.py"
+    if not path.is_file():
+        fail(f"{config_file}: harness {name!r}: no file {path}")
+    spec = importlib.util.spec_from_file_location(f"bench_arch_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    missing = [n for n in CONTRACT if not hasattr(mod, n)]
+    if missing:
+        fail(f"{path} (the harness of {config_file}) lacks {missing}")
+    return mod
 
 
 # ---------------------------------------------------------------------------
@@ -193,7 +220,8 @@ def build_runtime(cell: Cell, cfg, params, controller, horizon_s: float):
 def warm_up(cell: Cell, rt) -> None:
     """Compile every program the window will run: the whole start-of-life
     path and the decode step (two requests), and the restored-KV injection
-    into every arena slot (its slot index is a static argument)."""
+    into every arena slot (its slot index is a static argument), with the
+    stand-in hand-off of the configuration's architecture module."""
     import jax
 
     from repro.core.kvcache import KVCache
@@ -208,12 +236,8 @@ def warm_up(cell: Cell, rt) -> None:
 
     serve_pair(WARM_PROMPT_SEED)
     dw = rt.decode_workers[0]
-    m = rt.model_cfg
-    shape = (m.num_layers, m.kv_heads, int(mix["prompt_tokens"]),
-             m.resolved_head_dim)
-    rng = np.random.default_rng(0)
-    kv = KVCache(rng.standard_normal(shape).astype(np.float32),
-                 rng.standard_normal(shape).astype(np.float32))
+    kv = KVCache(*cell.harness.warm_handoff(rt.model_cfg,
+                                            int(mix["prompt_tokens"])))
     for idx in range(dw.n_slots):
         dw.inject_restored(kv, idx)
     # Once written from the host, the arena's arrays are committed to the
@@ -350,19 +374,7 @@ def run_once(cell: Cell, args, seed: int, rate: float, jax, devices,
 def check_sizes(cell: Cell, cfg, rehearse: bool) -> None:
     """The program's registered sizes must be the configuration file's."""
     used = cell.config["rehearsal"] if rehearse else cell.config["used"]
-    have = {"hidden_size": cfg.d_model, "num_hidden_layers": cfg.num_layers,
-            "num_attention_heads": cfg.num_heads,
-            "num_key_value_heads": cfg.kv_heads,
-            "head_dim": cfg.resolved_head_dim,
-            "intermediate_size": cfg.d_ff, "vocab_size": cfg.vocab_size,
-            "tie_word_embeddings": cfg.tie_embeddings}
-    arch = cell.config["architecture"]
-    have_arch = {"rmsnorm_eps": cfg.rmsnorm_eps, "rope_theta": cfg.rope_theta,
-                 "qk_norm": cfg.qk_norm}
-    bad = {k: (v, used.get(k)) for k, v in have.items()
-           if k in used and used[k] != v}
-    bad.update({k: (v, arch[k]) for k, v in have_arch.items()
-                if arch[k] != v})
+    bad = cell.harness.check(cfg, used, cell.config["architecture"])
     if bad:
         fail(f"the program's {cfg.name} differs from "
              f"{cell.config_entry['file']}: {bad}")
@@ -391,10 +403,8 @@ def compare(cell: Cell, res: Result, seed: int, rehearse: bool,
             control: bool):
     """Widest gap of the served tokens, and with ``control`` that of the
     fp8 control's tokens (None where nothing finished)."""
-    from reference import Reference
-
     model = dict(cell.config["rehearsal" if rehearse else "used"])
-    ref = Reference(model, cell.config["architecture"], seed)
+    ref = cell.harness.Reference(model, cell.config["architecture"], seed)
     if not res.sample:
         return None, None
     gaps, ctrl = ref.gaps(res.sample, control=control)
@@ -434,11 +444,9 @@ class Context:
     """What a per-layer metric reads."""
 
     def __init__(self, cell: Cell, res: Result, trace, peak):
-        import counts
-
         self.cell, self.mix, self.model = cell, cell.mix, cell.model
         self.rows, self.spans, self.trace = res.rows, res.recorder, trace
-        self.peak, self.counts = peak, counts
+        self.peak, self.counts = peak, cell.harness
 
 
 def main(argv=None) -> int:

@@ -5,14 +5,19 @@ import re
 
 import pytest
 
+import run
+from archs import CONTRACT
 from conftest import BENCH, ROOT
 
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-WIDTHS = re.compile(r"(hidden|intermediate|latent|state|projection|head|"
-                    r"_dim$|_rank$|expansion|experts_per_tok)")
+# Keys that size a width (hidden, intermediate, latent, state, projection,
+# head or window sizes, expansion factors, experts per token), which
+# ``reduced`` may never name; depth, expert and vocabulary counts it may.
+WIDTHS = re.compile(r"(hidden_size|intermediate|latent|state|projection|"
+                    r"head|window|_dim$|_rank$|expan|experts_per_tok)")
 
 
 def _line_ok(text: str) -> bool:
@@ -48,12 +53,28 @@ def test_config_resolves(entry):
                      if conf["used"].get(k) != conf["published"][k])
     assert changed == sorted(entry["reduced"])
     assert not any(WIDTHS.search(k) for k in entry["reduced"])
+    assert (BENCH / "archs" / f"{conf['harness']}.py").is_file()
+    mod = run.load_harness(conf, entry["file"])
+    assert all(callable(getattr(mod, n)) for n in CONTRACT)
     assert len(entry["reduced"]) <= 16
     assert all(NAME.match(k) for k in entry["reduced"])
     assert (BENCH / "profiles" / f"{entry['name']}.jsonl").is_file()
     assert any(w["config"] == entry["name"] for w in SPEC["workloads"])
     limits = conf["limits"]
     assert set(limits) == {"chip", "rehearsal"}
+
+
+@pytest.mark.parametrize("key,width", [
+    ("num_hidden_layers", False), ("num_experts", False),
+    ("n_routed_experts", False), ("vocab_size", False),
+    ("tie_word_embeddings", False),
+    ("hidden_size", True), ("intermediate_size", True),
+    ("moe_intermediate_size", True), ("head_dim", True),
+    ("kv_lora_rank", True), ("mamba_d_state", True), ("mamba_expand", True),
+    ("num_experts_per_tok", True), ("sliding_window", True),
+])
+def test_reduced_may_cut_depth_and_counts_but_no_width(key, width):
+    assert bool(WIDTHS.search(key)) is width
 
 
 @pytest.mark.parametrize("cell", SPEC["workloads"], ids=lambda w: w["name"])
