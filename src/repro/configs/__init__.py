@@ -21,8 +21,10 @@ def register(cfg: ModelConfig) -> ModelConfig:
 
 
 def get_config(name: str) -> ModelConfig:
+    """A registered configuration; ``<arch>-reduced`` that is not
+    registered itself is :func:`reduce_config` of ``<arch>``."""
     _ensure_loaded()
-    if name.endswith("-reduced"):
+    if name.endswith("-reduced") and name not in _REGISTRY:
         return reduce_config(get_config(name[: -len("-reduced")]))
     if name not in _REGISTRY:
         raise KeyError(f"unknown arch '{name}'; known: {sorted(_REGISTRY)}")

@@ -3,6 +3,8 @@ paper's own evaluation models and a tiny byte-LM for the serving engine.
 
 Each assigned arch also has per-file aliases under ``repro/configs/<id>.py``.
 """
+from dataclasses import replace
+
 from repro.configs import register
 from repro.configs.base import ModelConfig
 
@@ -43,14 +45,36 @@ MINICPM_2B = register(ModelConfig(
 
 # jamba-v0.1-52b [hybrid] 32L d_model=4096 32H (GQA kv=8) d_ff=14336
 #   vocab=65536, MoE 16e top-2 — Mamba+attn 1:7 interleave (attn at index 4
-#   of each 8-layer block), MoE every other layer [arXiv:2403.19887]
+#   of each 8-layer block), MoE every other layer [arXiv:2403.19887];
+#   Jamba's mixer norms dt, B and C, and its attention has no RoPE
 JAMBA_52B = register(ModelConfig(
     name="jamba-v0.1-52b", family="hybrid", num_layers=32, d_model=4096,
     num_heads=32, kv_heads=8, d_ff=14336, vocab_size=65536,
-    ssm=True, attn_period=8, attn_offset=4, ssm_state=16,
+    ssm=True, attn_period=8, attn_offset=4, ssm_state=16, ssm_norms=True,
+    use_rope=False,
     moe=True, num_experts=16, experts_per_token=2, moe_period=2,
     sub_quadratic=True,
 ))
+
+# jamba2-3b [hybrid] 28L d_model=2560 20H (MQA kv=1, head 128) d_ff=8192
+#   vocab=65536, tied head — Mamba-1 on 26 layers (d_state 16, d_conv 4,
+#   expand 2, dt_rank 160, dt/B/C norms), attention at layers 7 and 21
+#   (period 14, offset 7) with no positional encoding, a dense MLP on every
+#   layer (num_experts 1) [hf:ai21labs/AI21-Jamba2-3B]
+JAMBA2_3B = register(ModelConfig(
+    name="jamba2-3b", family="hybrid", num_layers=28, d_model=2560,
+    num_heads=20, kv_heads=1, d_ff=8192, vocab_size=65536, head_dim=128,
+    ssm=True, attn_period=14, attn_offset=7, ssm_state=16, ssm_conv=4,
+    ssm_expand=2, ssm_norms=True, use_rope=False, tie_embeddings=True,
+    sub_quadratic=True,
+))
+
+# Its CPU rehearsal: one whole period of 14 layers at a fifth of the width
+# (heads of the published 128).  Narrower, the tied head ranks each
+# position's own token first under the benchmark's N(0, 0.02) weights.
+JAMBA2_3B_REDUCED = register(replace(
+    JAMBA2_3B, name="jamba2-3b-reduced", num_layers=14, d_model=512,
+    num_heads=4, kv_heads=1, head_dim=128, d_ff=1024, vocab_size=512))
 
 # whisper-small [audio] 12L d_model=768 12H d_ff=3072 vocab=51865
 #   enc-dec, conv frontend (stub) [arXiv:2212.04356]

@@ -3,7 +3,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field, replace
-from typing import List, Optional, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 
 @dataclass(frozen=True)
@@ -26,6 +26,7 @@ class ModelConfig:
     local_global_period: int = 0     # >0: alternate local/global with period 2
     rope_theta: float = 10000.0
     mrope: bool = False              # qwen2-vl 3-section M-RoPE
+    use_rope: bool = True            # False: no positional encoding (jamba)
 
     # MLP / MoE
     moe: bool = False
@@ -44,6 +45,7 @@ class ModelConfig:
     ssm_state: int = 16
     ssm_conv: int = 4
     ssm_expand: int = 2
+    ssm_norms: bool = False          # jamba: RMSNorm on dt, B and C in the mixer
 
     # encoder-decoder (whisper)
     encoder_decoder: bool = False
@@ -87,6 +89,8 @@ class ModelConfig:
                 di, s, r = self.d_inner, self.ssm_state, self.dt_rank
                 n += d * 2 * di + di * self.ssm_conv + di * (r + 2 * s)
                 n += r * di + di * s + di + di * d
+                if self.ssm_norms:
+                    n += r + 2 * s
             else:
                 n += attn_params
         if self.encoder_decoder:  # decoder cross-attention blocks
@@ -145,16 +149,18 @@ class ModelConfig:
             for i in range(self.num_layers)
         ]
 
+    def layer_periods(self) -> Tuple[int, ...]:
+        """Candidate lengths of the repeating layer pattern, shortest
+        first: the divisors of the least common multiple of the periods
+        the configuration sets (attention, MoE, local/global)."""
+        attn = self.attn_period if self.ssm and self.attn_period else 1
+        base = math.lcm(attn, self.moe_period if self.moe else 1,
+                        2 if self.local_global_period > 0 else 1)
+        return tuple(p for p in range(1, base + 1) if base % p == 0)
+
     def scan_period(self) -> int:
         """Length of the repeating layer pattern (for scan-over-layers)."""
-        specs = self.layer_specs()
-        for period in (1, 2, 4, 8, 16):
-            if len(specs) % period:
-                continue
-            blocks = [tuple(specs[i : i + period]) for i in range(0, len(specs), period)]
-            if all(b == blocks[0] for b in blocks):
-                return period
-        return 0  # irregular — no scan
+        return repeat_period(self.layer_specs(), self.layer_periods())
 
 
 @dataclass(frozen=True)
@@ -162,6 +168,16 @@ class LayerSpec:
     kind: str  # attn | ssm
     moe: bool
     local: bool
+
+
+def repeat_period(specs: Sequence[LayerSpec], periods: Sequence[int]) -> int:
+    """The first of ``periods`` whose block ``specs`` repeats whole, or 0
+    (irregular: no scan)."""
+    for period in periods:
+        if specs and len(specs) % period == 0 and all(
+                specs[i] == specs[i % period] for i in range(len(specs))):
+            return period
+    return 0
 
 
 @dataclass(frozen=True)

@@ -3,26 +3,59 @@ the page-table bookkeeping for the paged decode arena (DESIGN.md §12).
 
 Layout: ``k, v : (num_layers, kv_heads, seq, head_dim)`` float32 arrays that
 *logically* represent bf16 wire data (2 bytes/elem), matching the paper's
-BF16 baseline accounting.
+BF16 baseline accounting; ``num_layers`` counts the attention layers.  A
+hybrid model's hand-off also carries the recurrent state of its SSM layers
+(``state``), which crosses exactly, in the types the model keeps it in.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List
+from typing import Dict, List, Optional
 
+import ml_dtypes
 import numpy as np
 
 from repro.core.strategy import SCALE_BYTES, SOURCE_BYTES
+
+# The SSM layers' recurrent state as the model keeps it: the scan state in
+# float32, the causal conv's last inputs in the compute type (bf16).
+STATE_DTYPES = {"ssm": np.dtype(np.float32),
+                "conv": np.dtype(ml_dtypes.bfloat16)}
+
+
+def state_nbytes(state: Optional[Dict[str, np.ndarray]]) -> int:
+    """Bytes of a hand-off's recurrent state (0 for none)."""
+    return sum(int(a.nbytes) for a in (state or {}).values())
+
+
+def handoff_bytes(cfg, seq: int) -> int:
+    """The uncompressed hand-off of one ``seq``-token prompt of the model
+    ``cfg``: every attention layer's K and V (logical bf16), plus every
+    SSM layer's scan state (float32) and conv state (bf16)."""
+    specs = cfg.layer_specs()
+    n_attn = sum(s.kind == "attn" for s in specs)
+    kv = 2 * n_attn * cfg.kv_heads * cfg.resolved_head_dim * seq
+    per_ssm = (cfg.d_inner * cfg.ssm_state * STATE_DTYPES["ssm"].itemsize
+               + cfg.d_inner * (cfg.ssm_conv - 1)
+               * STATE_DTYPES["conv"].itemsize)
+    return kv * SOURCE_BYTES + (len(specs) - n_attn) * per_ssm
 
 
 @dataclass
 class KVCache:
     k: np.ndarray  # (L, H, S, D)
     v: np.ndarray  # (L, H, S, D)
+    # SSM layers in layer order: {"ssm": (Ls, d_inner, d_state) float32,
+    # "conv": (Ls, d_inner, d_conv - 1) bf16}; None for attention-only.
+    state: Optional[Dict[str, np.ndarray]] = None
 
     def __post_init__(self):
         assert self.k.shape == self.v.shape, (self.k.shape, self.v.shape)
         assert self.k.ndim == 4
+        if self.state is not None:
+            assert set(self.state) == set(STATE_DTYPES), set(self.state)
+            self.state = {n: np.asarray(a, STATE_DTYPES[n])
+                          for n, a in self.state.items()}
 
     @property
     def shape(self):
@@ -45,8 +78,10 @@ class KVCache:
         return self.k.shape[3]
 
     def nbytes_wire(self) -> int:
-        """Bytes of the uncompressed payload on the wire (logical bf16)."""
-        return int(self.k.size + self.v.size) * SOURCE_BYTES
+        """Bytes of the uncompressed payload on the wire: K and V in
+        logical bf16, plus the recurrent state as held."""
+        return (int(self.k.size + self.v.size) * SOURCE_BYTES
+                + state_nbytes(self.state))
 
     @staticmethod
     def random(num_layers=4, kv_heads=4, seq=128, head_dim=64, seed=0,

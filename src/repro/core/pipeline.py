@@ -20,7 +20,7 @@ from typing import TYPE_CHECKING, Any, Dict, List, Optional, Tuple, Union
 import numpy as np
 
 from repro.core import codecs
-from repro.core.kvcache import KVCache
+from repro.core.kvcache import KVCache, state_nbytes
 from repro.core.quantizers import (
     QuantBucket,
     QuantizedTensor,
@@ -71,12 +71,17 @@ class CompressedKV:
     k_ctx: Dict[str, Any]
     v_ctx: Dict[str, Any]
     identity_payload: Optional[bytes] = None  # bypass path
+    # The SSM layers' recurrent state (KVCache.state): no strategy touches
+    # it; it crosses as held, exact.
+    state: Optional[Dict[str, np.ndarray]] = None
 
     # ------------------------------------------------------------------
     def payload_bytes(self) -> int:
         if self.identity_payload is not None:
-            return len(self.identity_payload)
-        return sum(len(b.payload) for b in self.k_buckets + self.v_buckets)
+            kv = len(self.identity_payload)
+        else:
+            kv = sum(len(b.payload) for b in self.k_buckets + self.v_buckets)
+        return kv + state_nbytes(self.state)
 
     def meta_bytes(self) -> int:
         if self.identity_payload is not None:
@@ -89,7 +94,8 @@ class CompressedKV:
         return self.payload_bytes() + self.meta_bytes()
 
     def original_bytes(self) -> int:
-        return int(np.prod(self.shape)) * 2 * SOURCE_BYTES
+        return (int(np.prod(self.shape)) * 2 * SOURCE_BYTES
+                + state_nbytes(self.state))
 
     def compression_ratio(self) -> float:
         return self.original_bytes() / max(self.total_bytes(), 1)
@@ -148,7 +154,9 @@ class CompressionPipeline:
         """``kv`` is a host :class:`KVCache`, or a prompt's KV still on the
         device (:class:`repro.core.quality.DeviceKV`): the identity payload
         is then cast to fp16 on the device and pulled as is, and every
-        other strategy runs on the float32 KV pulled to the host."""
+        other strategy runs on the float32 KV pulled to the host.  The
+        strategy compresses K and V; a recurrent state rides along as it
+        is."""
         cfg = self.strategy
         if is_identity(cfg):
             if isinstance(kv, KVCache):
@@ -158,7 +166,8 @@ class CompressionPipeline:
             else:
                 payload = kv.fp16().tobytes()
             return CompressedKV(cfg, kv.shape, [], [], {"kind": "none"},
-                                {"kind": "none"}, identity_payload=payload)
+                                {"kind": "none"}, identity_payload=payload,
+                                state=kv.state)
         if not isinstance(kv, KVCache):
             kv = kv.host()
 
@@ -176,7 +185,7 @@ class CompressionPipeline:
             strategy=cfg, shape=kv.shape,
             k_buckets=_encode_quantized(k_q, cfg.codec),
             v_buckets=_encode_quantized(v_q, cfg.codec),
-            k_ctx=k_ctx, v_ctx=v_ctx,
+            k_ctx=k_ctx, v_ctx=v_ctx, state=kv.state,
         )
 
     # ------------------------------------------------------------------
@@ -188,7 +197,7 @@ class CompressionPipeline:
                                  count=2 * n).astype(np.float32)
             k = flat[:n].reshape(comp.shape)
             v = flat[n:].reshape(comp.shape)
-            return KVCache(k, v)
+            return KVCache(k, v, comp.state)
 
         # The quantizer operated on *transformed* tensors whose channel dim
         # may have been padded (hadamard); recover that shape.
@@ -200,7 +209,7 @@ class CompressionPipeline:
         v_t = v_q.dequantize()
         k = invert_transform(k_t, comp.k_ctx)
         v = invert_transform(v_t, comp.v_ctx)
-        return KVCache(k, v)
+        return KVCache(k, v, comp.state)
 
     @staticmethod
     def _transformed_shape(shape, ctx) -> Tuple[int, int, int, int]:

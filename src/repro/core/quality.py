@@ -19,9 +19,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
-from repro.core.kvcache import KVCache
+from repro.core.kvcache import KVCache, handoff_bytes, state_nbytes
 from repro.core.pipeline import CompressionPipeline
-from repro.core.strategy import SOURCE_BYTES, StrategyConfig, is_identity
+from repro.core.strategy import StrategyConfig, is_identity
 from repro.data.synthetic import WORKLOADS, make_batch, make_prompt
 from repro.data.tokenizer import ByteTokenizer
 
@@ -82,78 +82,106 @@ def get_reference_model(steps: int = REF_STEPS, seed: int = 0):
 
 
 # ---------------------------------------------------------------------------
-# Cache <-> KVCache conversion (attention layers, dense stacks)
+# Cache <-> KVCache conversion (attention layers and SSM state, dense stacks)
 # ---------------------------------------------------------------------------
+def _layers_of(cfg, caches, kind: str):
+    """``(prefix, blocks, n_blocks)``: the cache leaves of the layers of
+    ``kind`` ("attn" | "ssm"), prefix layers and one period's layers (each
+    stacked over the scanned blocks), in layer order."""
+    from repro.models.transformer import plan_stack
+
+    plan = plan_stack(cfg)
+    prefix = tuple(caches["prefix"][f"layer{i}"]
+                   for i, s in enumerate(plan.prefix_specs) if s.kind == kind)
+    blocks = tuple(caches["blocks"][f"layer{j}"]
+                   for j, s in enumerate(plan.period_specs) if s.kind == kind)
+    return prefix, blocks, plan.n_blocks
+
+
+def _rows(prefix, blocks, key: str, batch_idx, upto=None):
+    """One batch element's ``key`` leaf of every layer, stacked in layer
+    order: the prefix layers, then the scanned blocks block by block."""
+    layers = [jax.lax.dynamic_index_in_dim(c[key], batch_idx, 0,
+                                           False)[None, :upto]
+              for c in prefix]
+    if blocks:
+        # block leaves (n_blocks, B, ...)
+        per = jnp.stack([jax.lax.dynamic_index_in_dim(
+            c[key], batch_idx, 1, False)[:, :upto] for c in blocks], 1)
+        layers.append(per.reshape(-1, *per.shape[2:]))
+    return jnp.concatenate(layers)
+
+
 @partial(jax.jit, static_argnames=("upto", "dtype"))
-def _gather_kv(prefix, blocks, batch_idx, upto: int, dtype):
-    """One batch element's attention K and V as ONE (2, L, H, S, D) array
-    in ``dtype``: K then V, the ``prefix`` layers first, then the scanned
-    ``blocks`` block by block (layer order of the stack)."""
-    out = []
-    for t in ("k", "v"):
-        # prefix leaves (B, S_max, H, D); block leaves (n_blocks, B, ...)
-        layers = [jax.lax.dynamic_index_in_dim(c[t], batch_idx, 0,
-                                               False)[None, :upto]
-                  for c in prefix]
-        if blocks:
-            per = jnp.stack([jax.lax.dynamic_index_in_dim(
-                c[t], batch_idx, 1, False)[:, :upto] for c in blocks], 1)
-            layers.append(per.reshape(-1, *per.shape[2:]))
-        out.append(jnp.concatenate(layers).swapaxes(1, 2).astype(dtype))
-    return jnp.stack(out)
+def _gather_kv(prefix, blocks, states, batch_idx, upto: int, dtype):
+    """One batch element's hand-off: its attention K and V as ONE (2, L, H,
+    S, D) array in ``dtype`` (K then V, layer order), and the SSM layers'
+    ``states`` (``(prefix, blocks)`` of their cache leaves) as
+    ``{"ssm", "conv"}`` arrays in their own types, or None."""
+    kv = jnp.stack([_rows(prefix, blocks, t, batch_idx, upto)
+                    .swapaxes(1, 2).astype(dtype) for t in ("k", "v")])
+    if not (states[0] or states[1]):
+        return kv, None
+    return kv, {t: _rows(*states, t, batch_idx) for t in ("ssm", "conv")}
 
 
 class DeviceKV:
-    """One batch element's attention KV, still in the device cache pytree
-    that a prefill wrote: the source a :class:`CompressionPipeline` pulls
-    from.  Its shape and wire size are known without a pull; each pull is
-    one jitted gather and ONE device->host transfer (span ``kv_pull``:
-    ``bytes`` that crossed, ``transfers``, and ``device_cast`` 1 where the
-    device made the wire payload, 0 where the host widens)."""
+    """One batch element's hand-off, still in the device cache pytree that
+    a prefill wrote: the source a :class:`CompressionPipeline` pulls from.
+    Its shape and wire size are known without a pull; each pull is one
+    jitted gather and one device->host transfer of each array it made
+    (span ``kv_pull``: ``bytes`` that crossed, ``state_bytes`` of them
+    the SSM layers' state, ``transfers``, and ``device_cast`` 1 where the
+    device made the wire payload, 0 where the host widens).  ``state``
+    holds the recurrent state of the last pull (None before one, and for
+    attention-only models)."""
 
     def __init__(self, cfg, caches, batch_idx: int, upto: int):
-        from repro.models.transformer import plan_stack
-
-        plan = plan_stack(cfg)
-        self._prefix = tuple(caches["prefix"][f"layer{i}"]
-                             for i, s in enumerate(plan.prefix_specs)
-                             if s.kind == "attn")
-        self._blocks = tuple(caches["blocks"][f"layer{j}"]
-                             for j, s in enumerate(plan.period_specs)
-                             if s.kind == "attn")
+        self._prefix, self._blocks, n_blocks = _layers_of(cfg, caches,
+                                                          "attn")
+        self._states = _layers_of(cfg, caches, "ssm")[:2]
         self._batch_idx = batch_idx
         self._upto = upto
         leaf = (self._prefix + self._blocks)[0]["k"]
-        n_layers = len(self._prefix) + len(self._blocks) * plan.n_blocks
+        n_layers = len(self._prefix) + len(self._blocks) * n_blocks
         self.shape = (n_layers, leaf.shape[-2], upto, leaf.shape[-1])
         self.cache_dtype = np.dtype(leaf.dtype)
+        self._wire_bytes = handoff_bytes(cfg, upto)
+        self.state: Optional[Dict[str, np.ndarray]] = None
 
     def nbytes_wire(self) -> int:
-        """Bytes of the uncompressed payload on the wire (logical bf16),
-        as :meth:`KVCache.nbytes_wire`."""
-        return 2 * int(np.prod(self.shape)) * SOURCE_BYTES
+        """Bytes of the uncompressed payload on the wire, as
+        :meth:`KVCache.nbytes_wire`: K and V in logical bf16, plus the
+        state as held."""
+        return self._wire_bytes
 
     def _pull(self, dtype, device_cast: int) -> np.ndarray:
         from repro.serving import tracing
 
         with tracing.span("kv_pull") as sp:
-            arr = np.asarray(_gather_kv(self._prefix, self._blocks,
-                                        self._batch_idx, self._upto,
-                                        np.dtype(dtype)))
+            # lint: sync-ok(the hand-off leaves the device here, once per request)
+            kv, state = jax.device_get(_gather_kv(
+                self._prefix, self._blocks, self._states, self._batch_idx,
+                self._upto, np.dtype(dtype)))
             if sp:
-                sp.add(bytes=arr.nbytes, transfers=1, device_cast=device_cast)
-        return arr
+                n_state = state_nbytes(state)
+                sp.add(bytes=kv.nbytes + n_state, state_bytes=n_state,
+                       transfers=1 + len(state or ()),
+                       device_cast=device_cast)
+        self.state = state
+        return kv
 
     def fp16(self) -> np.ndarray:
         """K then V as one C-ordered (2, L, H, S, D) float16 host array,
-        cast on the device: the identity hand-off's wire payload."""
+        cast on the device: the identity hand-off's wire payload (the
+        state comes with it, in :attr:`state`)."""
         return self._pull(np.float16, device_cast=1)
 
     def host(self) -> KVCache:
         """The float32 :class:`KVCache`: pulled in the cache dtype and
-        widened on the host (exact from bf16)."""
+        widened on the host (exact from bf16), with the state."""
         kv = self._pull(self.cache_dtype, device_cast=0).astype(np.float32)
-        return KVCache(kv[0], kv[1])
+        return KVCache(kv[0], kv[1], self.state)
 
 
 def extract_kv(cfg, caches, batch_idx: int, upto: int) -> KVCache:
@@ -201,7 +229,8 @@ def _put_like(buf, arr):
 
 def inject_kv(cfg, caches, batch_idx: int, kv: KVCache):
     """Write a (possibly lossy) KVCache back into the cache pytree, on the
-    device that holds it."""
+    device that holds it: the attention layers' K and V, and the SSM
+    layers' state where the hand-off carries one (:func:`inject_state`)."""
     from repro.models.transformer import plan_stack
 
     plan = plan_stack(cfg)
@@ -238,7 +267,71 @@ def inject_kv(cfg, caches, batch_idx: int, kv: KVCache):
         v_buf = c["v"].at[:, batch_idx, :upto].set(_put_like(c["v"], varr))
         new_blocks[name] = {"k": k_buf, "v": v_buf}
         li += 1
-    return {"prefix": new_prefix, "blocks": new_blocks}
+    caches = {"prefix": new_prefix, "blocks": new_blocks}
+    if kv.state is not None:
+        caches = inject_state(cfg, caches, batch_idx, kv.state)
+    return caches
+
+
+@jax.jit
+def _set_state(prefix, blocks, slot, state):
+    """Row ``slot`` of every SSM layer's state leaves set from ``state``
+    (``{"ssm", "conv"}``, one row per SSM layer in :func:`_rows` order)."""
+    n = len(blocks)
+    new_prefix = tuple({t: c[t].at[slot].set(state[t][i].astype(c[t].dtype))
+                        for t in c}
+                       for i, c in enumerate(prefix))
+    new_blocks = tuple({t: c[t].at[:, slot].set(
+        state[t][len(prefix) + j::n].astype(c[t].dtype)) for t in c}
+        for j, c in enumerate(blocks))
+    return new_prefix, new_blocks
+
+
+def inject_state(cfg, caches, batch_idx: int, state):
+    """Write one request's recurrent state (:attr:`KVCache.state`) into row
+    ``batch_idx`` of every SSM layer of the cache pytree, pushed from the
+    host in the types it is held in (span ``state_inject``)."""
+    from repro.models.transformer import plan_stack
+    from repro.serving import tracing
+
+    plan = plan_stack(cfg)
+    with tracing.span("state_inject"):
+        prefix, blocks, _ = _layers_of(cfg, caches, "ssm")
+        where = jax.tree_util.tree_leaves(caches)[0].sharding
+        new_prefix, new_blocks = _set_state(
+            prefix, blocks, jnp.asarray(batch_idx, jnp.int32),
+            jax.device_put(dict(state), where))
+        out = {"prefix": dict(caches["prefix"]),
+               "blocks": dict(caches["blocks"])}
+        for part, specs, new in (("prefix", plan.prefix_specs, new_prefix),
+                                 ("blocks", plan.period_specs, new_blocks)):
+            names = [f"layer{i}" for i, s in enumerate(specs)
+                     if s.kind == "ssm"]
+            out[part].update(zip(names, new))
+    return out
+
+
+def _hold_parked_state(cfg, new, old, mask):
+    """The decoded cache with every SSM layer's state of a parked row
+    (``mask`` False) kept as it was.  A parked row's attention write is
+    inert at the scratch position, but its recurrent state would advance
+    on the row's dummy token.  Attention-only models come back as decoded."""
+    from repro.models.transformer import plan_stack
+
+    plan = plan_stack(cfg)
+    out = {"prefix": dict(new["prefix"]), "blocks": dict(new["blocks"])}
+    for part, specs, axis in (("prefix", plan.prefix_specs, 0),
+                              ("blocks", plan.period_specs, 1)):
+        for i, spec in enumerate(specs):
+            if spec.kind != "ssm":
+                continue
+            name = f"layer{i}"
+            out[part][name] = {
+                t: jnp.where(mask.reshape((1,) * axis + (-1,)
+                                          + (1,) * (a.ndim - axis - 1)),
+                             a, old[part][name][t])
+                for t, a in new[part][name].items()}
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -565,9 +658,10 @@ def _jitted_steps(cfg_name: str, seq: int, batch: int, max_len: int):
     flags.  Every slot advances in ONE model call; parked rows (mask
     False — free slots and this iteration's fresh prefills) are pinned to
     the scratch position ``max_len - 1``, which no live query position
-    ever attends to, so their cache writes are inert.  The next token per
-    slot comes from an on-device argmax; the caller pulls the (B,) token
-    vector back once per iteration.
+    ever attends to, so their cache writes are inert, and an SSM layer's
+    state of a parked row is kept bit for bit (``where(mask, new, old)``).
+    The next token per slot comes from an on-device argmax; the caller
+    pulls the (B,) token vector back once per iteration.
     """
     from repro.models import decode_step, prefill
 
@@ -577,9 +671,9 @@ def _jitted_steps(cfg_name: str, seq: int, batch: int, max_len: int):
 
     def _arena(p, c, t, pos, mask):
         pos = jnp.where(mask, pos, max_len - 1).astype(jnp.int32)
-        logits, c = decode_step(cfg, p, c, t, pos)
+        logits, new = decode_step(cfg, p, c, t, pos)
         nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-        return jnp.where(mask, nxt, 0), c
+        return jnp.where(mask, nxt, 0), _hold_parked_state(cfg, new, c, mask)
 
     return pre, dec, jax.jit(_arena)
 

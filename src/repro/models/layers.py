@@ -281,7 +281,7 @@ def apply_attention(
         if cfg.mrope:
             q = apply_mrope(q, positions, cfg.rope_theta)
             k = apply_mrope(k, positions, cfg.rope_theta)
-        else:
+        elif cfg.use_rope:
             q = apply_rope(q, positions, cfg.rope_theta)
             k = apply_rope(k, positions, cfg.rope_theta)
 

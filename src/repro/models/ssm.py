@@ -18,7 +18,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models.axes import Initializer, Pm
-from repro.models.layers import COMPUTE_DTYPE
+from repro.models.layers import COMPUTE_DTYPE, init_rmsnorm, rmsnorm
 
 
 def init_mamba(ini: Initializer, cfg: ModelConfig) -> Dict[str, Pm]:
@@ -27,7 +27,7 @@ def init_mamba(ini: Initializer, cfg: ModelConfig) -> Dict[str, Pm]:
     s = 1.0 / math.sqrt(d)
     # A initialised to -[1..n] per channel (S4D-real init).
     a_init = np.log(np.broadcast_to(np.arange(1, n + 1, dtype=np.float32), (di, n)))
-    return {
+    p = {
         "in_proj": ini.normal((d, 2 * di), ("embed", "mlp"), scale=s),
         "conv_w": ini.normal((di, kc), ("mlp", None), scale=0.5),
         "conv_b": ini.zeros((di,), ("mlp",)),
@@ -41,6 +41,11 @@ def init_mamba(ini: Initializer, cfg: ModelConfig) -> Dict[str, Pm]:
         "out_proj": ini.normal((di, d), ("mlp", "embed"),
                                scale=1.0 / math.sqrt(di)),
     }
+    if cfg.ssm_norms:
+        p["dt_norm"] = init_rmsnorm(ini, r)
+        p["b_norm"] = init_rmsnorm(ini, n)
+        p["c_norm"] = init_rmsnorm(ini, n)
+    return p
 
 
 def _causal_depthwise_conv(x, w, b, state: Optional[jnp.ndarray] = None):
@@ -64,11 +69,17 @@ def _causal_depthwise_conv(x, w, b, state: Optional[jnp.ndarray] = None):
 
 
 def _ssm_params(params, cfg: ModelConfig, x_conv):
-    """Input-dependent (dt, B, C) from the conv output."""
+    """Input-dependent (dt, B, C) from the conv output; with
+    ``cfg.ssm_norms`` (jamba) each of dt (before ``dt_proj``), B and C
+    passes an RMSNorm first."""
     n, r = cfg.ssm_state, cfg.dt_rank
     proj = jnp.einsum("bsd,dk->bsk", x_conv.astype(COMPUTE_DTYPE),
                       params["x_proj"].astype(COMPUTE_DTYPE)).astype(jnp.float32)
     dt_r, b_mat, c_mat = jnp.split(proj, [r, r + n], axis=-1)
+    if cfg.ssm_norms:
+        dt_r = rmsnorm(params["dt_norm"], dt_r, cfg.rmsnorm_eps)
+        b_mat = rmsnorm(params["b_norm"], b_mat, cfg.rmsnorm_eps)
+        c_mat = rmsnorm(params["c_norm"], c_mat, cfg.rmsnorm_eps)
     dt = jax.nn.softplus(
         jnp.einsum("bsr,rd->bsd", dt_r, params["dt_proj_w"].astype(jnp.float32))
         + params["dt_proj_b"].astype(jnp.float32)

@@ -16,7 +16,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import LayerSpec, ModelConfig
+from repro.configs.base import LayerSpec, ModelConfig, repeat_period
 from repro.models import layers as L
 from repro.models import ssm as S
 from repro.models.axes import Initializer, Pm, abstract_like_block, is_pm, split_tree, stack_block_params
@@ -39,13 +39,10 @@ def plan_stack(cfg: ModelConfig) -> StackPlan:
     # Pull an irregular prefix (e.g. deepseek dense first layer[s]) out front.
     for prefix_len in range(0, min(len(specs), 4)):
         rest = specs[prefix_len:]
-        for period in (1, 2, 4, 8, 16):
-            if len(rest) == 0 or len(rest) % period:
-                continue
-            blocks = [tuple(rest[i : i + period]) for i in range(0, len(rest), period)]
-            if all(b == blocks[0] for b in blocks):
-                return StackPlan(tuple(specs[:prefix_len]), blocks[0],
-                                 len(rest) // period)
+        period = repeat_period(rest, cfg.layer_periods())
+        if period:
+            return StackPlan(tuple(specs[:prefix_len]), tuple(rest[:period]),
+                             len(rest) // period)
     # Fully irregular: everything is prefix (no scan).
     return StackPlan(tuple(specs), (), 0)
 

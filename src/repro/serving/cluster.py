@@ -51,6 +51,7 @@ from repro.controller.latency_model import (
     baseline_latency,
     predicted_latency,
 )
+from repro.core.kvcache import handoff_bytes
 from repro.core.profiles import Profile
 from repro.core.quality import _prompts_for, get_reference_model
 from repro.data.tokenizer import ByteTokenizer
@@ -68,7 +69,7 @@ from repro.serving.network import (
     KVWire,
     seed_bandwidth,
 )
-from repro.serving.request import Request, kv_bytes_for
+from repro.serving.request import Request
 from repro.serving import tracing
 from repro.serving.scheduler import ContinuousScheduler, SchedulerConfig
 from repro.serving.topology import NetworkTopology, route_name
@@ -390,14 +391,12 @@ class ClusterRuntime:
         self._next_rid += 1
         tokens, _ = _prompts_for(workload, 1, self.cfg.seq, prompt_seed)
         tokens = np.asarray(tokens)[0]
-        m = self.model_cfg
         req = Request(
             rid=rid, workload=workload, arrival=self.clock,
             ctx_tokens=self.cfg.seq,
             out_tokens=(self.cfg.decode_tokens if out_tokens is None
                         else min(out_tokens, self.cfg.decode_tokens)),
-            kv_bytes=kv_bytes_for(self.cfg.seq, m.num_layers, m.kv_heads,
-                                  m.resolved_head_dim),
+            kv_bytes=handoff_bytes(self.model_cfg, self.cfg.seq),
             t_slo=t_slo, q_min=q_min, slo_class=slo_class,
             slo_metric=slo_metric,
             prefix_key=tuple(int(t) for t in tokens))

@@ -41,7 +41,7 @@ import numpy as np
 
 from repro.controller import Decision, ServiceAwareController, ServiceContext
 from repro.core import codecs
-from repro.core.kvcache import PageTable
+from repro.core.kvcache import PageTable, state_nbytes
 from repro.core.pipeline import CompressedKV, CompressionPipeline
 from repro.core.profiles import Profile
 from repro.core.quality import (
@@ -56,9 +56,13 @@ from repro.core.quality import (
     inject_quant_pages,
 )
 from repro.core.strategy import StrategyConfig, paged_eligible
+from repro.models.layers import COMPUTE_DTYPE
 from repro.serving import tracing
 from repro.serving.kvstore import TierSpec
 from repro.serving.request import Request
+
+# The arena holds keys and values in the compute type.
+KV_ITEMSIZE = np.dtype(COMPUTE_DTYPE).itemsize
 
 
 def _select_profile(controller: Optional[ServiceAwareController],
@@ -88,7 +92,8 @@ def compress_kvs(strategy: StrategyConfig, kvs: Sequence[Any]
         comps = [pipe.compress(kv) for kv in kvs]
         if sp:
             sp.add(kv_bytes=sum(kv.nbytes_wire() for kv in kvs),
-                   wire_bytes=sum(c.total_bytes() for c in comps))
+                   wire_bytes=sum(c.total_bytes() for c in comps),
+                   state_bytes=sum(state_nbytes(c.state) for c in comps))
     return comps, sum(c.total_bytes() for c in comps), sp.seconds
 
 
@@ -529,11 +534,13 @@ class DecodeWorker(_Placed):
         if self._arena is None:
             from repro.models.transformer import init_cache, plan_stack
             plan = plan_stack(self.model.cfg)
-            if any(s.kind != "attn"
-                   for s in plan.prefix_specs + plan.period_specs):
+            if ((self.cfg.paged or self.cfg.spec_k > 0)
+                    and any(s.kind != "attn"
+                            for s in plan.prefix_specs + plan.period_specs)):
                 raise NotImplementedError(
-                    "slot arena masking assumes attention-only caches "
-                    "(SSM states advance unmasked)")
+                    "SSM layers run in the dense arena's one-token step "
+                    "only: the paged arena and the speculative verify step "
+                    "hold no recurrent state per slot")
             # Allocated on the worker's device directly: never staged
             # through the default device, which may be another worker's.
             with jax.default_device(self.device):
@@ -595,7 +602,8 @@ class DecodeWorker(_Placed):
 
     def inject_restored(self, kv, idx: int) -> None:
         """Materialize arena row ``idx`` from a wire-restored KV (pushed
-        from the host in the arena's dtype)."""
+        from the host in the arena's dtype) and its recurrent state, if
+        any (pushed as held; span ``state_inject`` inside ``inject``)."""
         with tracing.span("inject") as sp:
             self.ensure_arena()
             if self.cfg.paged:
@@ -608,8 +616,9 @@ class DecodeWorker(_Placed):
                 self._arena = inject_kv(self.model.cfg, self._arena, idx,
                                         kv)
             if sp:
-                leaf = jax.tree_util.tree_leaves(self._arena)[0]
-                sp.add(bytes=(kv.k.size + kv.v.size) * leaf.dtype.itemsize)
+                n_state = state_nbytes(kv.state)
+                sp.add(bytes=(kv.k.size + kv.v.size) * KV_ITEMSIZE + n_state,
+                       state_bytes=n_state)
 
     def fetch_entry(self, entry, idx: int) -> Tuple[int, float]:
         """Land a stored pool entry in arena slot ``idx``.  Returns

@@ -52,35 +52,40 @@ def _caches(cfg, batch: int, seed: int):
             mag = 10.0 ** jax.random.uniform(a, leaf.shape, minval=-9.0,
                                              maxval=4.0)
             leaf = (jax.random.normal(b, leaf.shape) * mag).astype(leaf.dtype)
+        else:               # an SSM layer's scan or conv state
+            leaf = jax.random.normal(key, leaf.shape).astype(leaf.dtype)
         out.append(leaf)
     return jax.tree_util.tree_unflatten(tree, out)
 
 
 def _extract_kv_as_before(cfg, caches, batch_idx: int, upto: int) -> KVCache:
     """One device->host pull per layer and tensor, widened to float32 and
-    stacked on the host."""
+    stacked on the host; an SSM layer's scan and conv state pulled as
+    held, in layer order."""
     from repro.models.transformer import plan_stack
 
     plan = plan_stack(cfg)
     ks, vs = [], []
-    for i, spec in enumerate(plan.prefix_specs):
-        if spec.kind != "attn":
-            continue
-        c = caches["prefix"][f"layer{i}"]
-        ks.append(np.asarray(c["k"][batch_idx, :upto],
+    state = {"ssm": [], "conv": []}
+
+    def pull(c, row):
+        if "k" not in c:
+            for t in state:
+                state[t].append(np.asarray(c[t][row]))
+            return
+        ks.append(np.asarray(c["k"][row][:upto],
                              np.float32).transpose(1, 0, 2))
-        vs.append(np.asarray(c["v"][batch_idx, :upto],
+        vs.append(np.asarray(c["v"][row][:upto],
                              np.float32).transpose(1, 0, 2))
+
+    for i in range(len(plan.prefix_specs)):
+        pull(caches["prefix"][f"layer{i}"], batch_idx)
     for blk in range(plan.n_blocks):
-        for j, spec in enumerate(plan.period_specs):
-            if spec.kind != "attn":
-                continue
-            c = caches["blocks"][f"layer{j}"]
-            ks.append(np.asarray(c["k"][blk, batch_idx, :upto],
-                                 np.float32).transpose(1, 0, 2))
-            vs.append(np.asarray(c["v"][blk, batch_idx, :upto],
-                                 np.float32).transpose(1, 0, 2))
-    return KVCache(np.stack(ks), np.stack(vs))
+        for j in range(len(plan.period_specs)):
+            pull(caches["blocks"][f"layer{j}"], (blk, batch_idx))
+    return KVCache(np.stack(ks), np.stack(vs),
+                   {t: np.stack(a) for t, a in state.items()}
+                   if state["ssm"] else None)
 
 
 def _worker(cfg, profile: Profile):
@@ -134,6 +139,14 @@ def test_select_and_compress_matches_the_host_path(plan, strategy):
     got, want = pipe.decompress(comp), pipe.decompress(ref)
     np.testing.assert_array_equal(got.k, want.k)
     np.testing.assert_array_equal(got.v, want.v)
+    _assert_same_state(got, want)
+
+
+def _assert_same_state(got: KVCache, want: KVCache):
+    assert (got.state is None) == (want.state is None)
+    for t in (want.state or {}):
+        assert got.state[t].dtype == want.state[t].dtype
+        np.testing.assert_array_equal(got.state[t], want.state[t])
 
 
 @pytest.mark.parametrize("plan", PLANS)
@@ -148,6 +161,7 @@ def test_extract_kv_keeps_its_float32_values(plan):
         assert got.k.dtype == got.v.dtype == np.float32
         np.testing.assert_array_equal(got.k, want.k)
         np.testing.assert_array_equal(got.v, want.v)
+        _assert_same_state(got, want)
 
 
 def test_the_fp16_payload_is_c_ordered_k_then_v():

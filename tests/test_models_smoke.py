@@ -128,3 +128,20 @@ def test_scan_period_detection():
     plan = plan_stack(get_config("deepseek-moe-16b"))
     assert len(plan.prefix_specs) == 1 and not plan.prefix_specs[0].moe
     assert plan.n_blocks == 27
+
+
+@pytest.mark.parametrize("arch,period,blocks", [
+    ("jamba2-3b", 14, 2),        # attention at 7 of each period of 14
+    ("jamba-v0.1-52b", 8, 4),    # attention every 8, MoE every 2
+    ("qwen3-4b", 1, 36),
+])
+def test_stack_plan_takes_its_periods_from_the_config(arch, period, blocks):
+    """The scan period comes from the configuration's own periods, so a
+    period outside (1, 2, 4, 8, 16) still scans instead of unrolling."""
+    from repro.models.transformer import plan_stack
+
+    cfg = get_config(arch)
+    assert cfg.layer_periods()[-1] % period == 0
+    plan = plan_stack(cfg)
+    assert cfg.scan_period() == len(plan.period_specs) == period
+    assert plan.prefix_specs == () and plan.n_blocks == blocks
