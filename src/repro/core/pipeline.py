@@ -189,15 +189,21 @@ class CompressionPipeline:
         )
 
     # ------------------------------------------------------------------
-    def decompress(self, comp: CompressedKV) -> KVCache:
+    def decompress(self, comp: CompressedKV, fp16: bool = False) -> KVCache:
+        """The float32 :class:`KVCache` of ``comp``.  With ``fp16``, an
+        identity payload's K and V come back as the fp16 halves of one
+        zero-copy view of its bytes, for a consumer that casts on the
+        device (:func:`repro.core.quality.inject_kv`); every other
+        strategy restores float32 either way."""
         cfg = comp.strategy
         if comp.identity_payload is not None:
             n = int(np.prod(comp.shape))
             flat = np.frombuffer(comp.identity_payload, dtype=np.float16,
-                                 count=2 * n).astype(np.float32)
-            k = flat[:n].reshape(comp.shape)
-            v = flat[n:].reshape(comp.shape)
-            return KVCache(k, v, comp.state)
+                                 count=2 * n)
+            if not fp16:
+                flat = flat.astype(np.float32)
+            kv = flat.reshape((2,) + tuple(comp.shape))
+            return KVCache(kv[0], kv[1], comp.state)
 
         # The quantizer operated on *transformed* tensors whose channel dim
         # may have been padded (hadamard); recover that shape.

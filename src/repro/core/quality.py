@@ -227,10 +227,85 @@ def _put_like(buf, arr):
     return jax.device_put(np.asarray(arr, buf.dtype), buf.sharding)
 
 
+def _kv_pair(kv: KVCache) -> np.ndarray:
+    """K then V as one C-ordered (2, L, H, S, D) array: a view where they
+    are the two halves of one buffer, as a restored identity payload's
+    are, else a copy."""
+    flat = kv.k.base
+    if (isinstance(flat, np.ndarray) and flat.ndim == 1
+            and flat.size == 2 * kv.k.size and flat.flags.c_contiguous
+            and kv.k.flags.c_contiguous and kv.v.flags.c_contiguous
+            and kv.k.ctypes.data == flat.ctypes.data
+            and kv.v.ctypes.data == flat.ctypes.data + kv.k.nbytes):
+        return flat.reshape((2,) + kv.shape)
+    return np.stack([kv.k, kv.v])
+
+
+@jax.jit
+def _scatter_kv(prefix, blocks, kv, slot):
+    """Row ``slot`` of every attention layer's K and V leaves set from
+    ``kv`` ((2, L, H, S, D), K then V in :func:`_rows` order: the prefix
+    layers, then each period layer across the blocks), cast to the
+    leaves' dtype.  ``slot`` is traced: one program serves every row."""
+    rows = kv.swapaxes(2, 3)                       # (2, L, S, H, D)
+    n_prefix, n = len(prefix), len(blocks)
+
+    def put(buf, upd, axis):
+        start = [0] * buf.ndim
+        start[axis] = slot
+        return jax.lax.dynamic_update_slice(
+            buf, jnp.expand_dims(upd.astype(buf.dtype), axis), tuple(start))
+
+    new_prefix = tuple({t: put(c[t], rows[i_t, i], 0)
+                        for i_t, t in enumerate(("k", "v"))}
+                       for i, c in enumerate(prefix))
+    new_blocks = tuple({t: put(c[t], rows[i_t, n_prefix + j::n], 1)
+                        for i_t, t in enumerate(("k", "v"))}
+                       for j, c in enumerate(blocks))
+    return new_prefix, new_blocks
+
+
+def _with_layers(cfg, caches, kind: str, new_prefix, new_blocks):
+    """``caches`` with the leaves of its layers of ``kind`` replaced by
+    ``(new_prefix, new_blocks)``, in :func:`_layers_of` order."""
+    from repro.models.transformer import plan_stack
+
+    plan = plan_stack(cfg)
+    out = {"prefix": dict(caches["prefix"]), "blocks": dict(caches["blocks"])}
+    for part, specs, new in (("prefix", plan.prefix_specs, new_prefix),
+                             ("blocks", plan.period_specs, new_blocks)):
+        names = [f"layer{i}" for i, s in enumerate(specs) if s.kind == kind]
+        out[part].update(zip(names, new))
+    return out
+
+
 def inject_kv(cfg, caches, batch_idx: int, kv: KVCache):
     """Write a (possibly lossy) KVCache back into the cache pytree, on the
     device that holds it: the attention layers' K and V, and the SSM
-    layers' state where the hand-off carries one (:func:`inject_state`)."""
+    layers' state where the hand-off carries one (:func:`inject_state`).
+
+    K and V in fp16 (a restored identity payload) cross as they are, in
+    one push, and one jitted program casts them and writes every layer's
+    row; any other KVCache is cast to the cache dtype on the host and
+    written layer by layer."""
+    if kv.k.dtype == np.float16:
+        prefix, blocks, _ = _layers_of(cfg, caches, "attn")
+        where = (prefix + blocks)[0]["k"].sharding
+        new_prefix, new_blocks = _scatter_kv(
+            prefix, blocks, jax.device_put(_kv_pair(kv), where),
+            jnp.asarray(batch_idx, jnp.int32))
+        caches = _with_layers(cfg, caches, "attn", new_prefix, new_blocks)
+    else:
+        caches = _inject_kv_host(cfg, caches, batch_idx, kv)
+    if kv.state is not None:
+        caches = inject_state(cfg, caches, batch_idx, kv.state)
+    return caches
+
+
+def _inject_kv_host(cfg, caches, batch_idx: int, kv: KVCache):
+    """The attention layers' K and V of ``kv`` cast to the cache dtype on
+    the host and pushed layer by layer (period layers stacked across the
+    blocks), each written by an eager scatter."""
     from repro.models.transformer import plan_stack
 
     plan = plan_stack(cfg)
@@ -267,10 +342,7 @@ def inject_kv(cfg, caches, batch_idx: int, kv: KVCache):
         v_buf = c["v"].at[:, batch_idx, :upto].set(_put_like(c["v"], varr))
         new_blocks[name] = {"k": k_buf, "v": v_buf}
         li += 1
-    caches = {"prefix": new_prefix, "blocks": new_blocks}
-    if kv.state is not None:
-        caches = inject_state(cfg, caches, batch_idx, kv.state)
-    return caches
+    return {"prefix": new_prefix, "blocks": new_blocks}
 
 
 @jax.jit
@@ -291,24 +363,15 @@ def inject_state(cfg, caches, batch_idx: int, state):
     """Write one request's recurrent state (:attr:`KVCache.state`) into row
     ``batch_idx`` of every SSM layer of the cache pytree, pushed from the
     host in the types it is held in (span ``state_inject``)."""
-    from repro.models.transformer import plan_stack
     from repro.serving import tracing
 
-    plan = plan_stack(cfg)
     with tracing.span("state_inject"):
         prefix, blocks, _ = _layers_of(cfg, caches, "ssm")
         where = jax.tree_util.tree_leaves(caches)[0].sharding
         new_prefix, new_blocks = _set_state(
             prefix, blocks, jnp.asarray(batch_idx, jnp.int32),
             jax.device_put(dict(state), where))
-        out = {"prefix": dict(caches["prefix"]),
-               "blocks": dict(caches["blocks"])}
-        for part, specs, new in (("prefix", plan.prefix_specs, new_prefix),
-                                 ("blocks", plan.period_specs, new_blocks)):
-            names = [f"layer{i}" for i, s in enumerate(specs)
-                     if s.kind == "ssm"]
-            out[part].update(zip(names, new))
-    return out
+        return _with_layers(cfg, caches, "ssm", new_prefix, new_blocks)
 
 
 def _hold_parked_state(cfg, new, old, mask):

@@ -672,7 +672,8 @@ class ClusterRuntime:
         # is actually consumed (virtual-clock default models the cost from
         # profile.s_dec, so running it would be pure benchmark tax).
         if self.cfg.pd_inject_restored or self.cfg.prefill_tok_s is None:
-            restored, t_wall = decompress_kvs([comp])
+            restored, t_wall = decompress_kvs(
+                [comp], fp16=self.cfg.pd_inject_restored and dw.takes_fp16)
         else:
             restored, t_wall = None, 0.0
         t_decompress = codec_cost(self.cfg, t_wall, ctx.kv_bytes,

@@ -97,11 +97,14 @@ def compress_kvs(strategy: StrategyConfig, kvs: Sequence[Any]
     return comps, sum(c.total_bytes() for c in comps), sp.seconds
 
 
-def decompress_kvs(comps: Sequence[CompressedKV]
+def decompress_kvs(comps: Sequence[CompressedKV], fp16: bool = False
                    ) -> Tuple[List[Any], float]:
-    """Restore wire payloads to KV.  Returns ``(kvs, measured_seconds)``."""
+    """Restore wire payloads to KV.  Returns ``(kvs, measured_seconds)``.
+    ``fp16`` only for a dense arena's injection: identity payloads then
+    stay fp16 views (:meth:`CompressionPipeline.decompress`)."""
     with tracing.timed("restore") as sp:
-        kvs = [CompressionPipeline(c.strategy).decompress(c) for c in comps]
+        kvs = [CompressionPipeline(c.strategy).decompress(c, fp16)
+               for c in comps]
         if sp:
             sp.add(bytes=sum(kv.k.nbytes + kv.v.nbytes for kv in kvs))
     return kvs, sp.seconds
@@ -600,10 +603,18 @@ class DecodeWorker(_Placed):
             if sp:
                 sp.add(bytes=0)
 
+    @property
+    def takes_fp16(self) -> bool:
+        """Whether :meth:`inject_restored` casts an fp16 restore on the
+        device: the dense arena does; the paged arena takes float32."""
+        return not self.cfg.paged
+
     def inject_restored(self, kv, idx: int) -> None:
-        """Materialize arena row ``idx`` from a wire-restored KV (pushed
-        from the host in the arena's dtype) and its recurrent state, if
-        any (pushed as held; span ``state_inject`` inside ``inject``)."""
+        """Materialize arena row ``idx`` from a wire-restored KV and its
+        recurrent state, if any (pushed as held; span ``state_inject``
+        inside ``inject``).  An fp16 restore into the dense arena crosses
+        as it is and is cast on the device (counter ``device_cast`` 1);
+        any other is cast to the arena's dtype on the host (0)."""
         with tracing.span("inject") as sp:
             self.ensure_arena()
             if self.cfg.paged:
@@ -618,7 +629,9 @@ class DecodeWorker(_Placed):
             if sp:
                 n_state = state_nbytes(kv.state)
                 sp.add(bytes=(kv.k.size + kv.v.size) * KV_ITEMSIZE + n_state,
-                       state_bytes=n_state)
+                       state_bytes=n_state,
+                       device_cast=int(self.takes_fp16
+                                       and kv.k.dtype == np.float16))
 
     def fetch_entry(self, entry, idx: int) -> Tuple[int, float]:
         """Land a stored pool entry in arena slot ``idx``.  Returns
@@ -651,7 +664,7 @@ class DecodeWorker(_Placed):
                     sp.add(bytes=kc.nbytes + ks.nbytes + vc.nbytes + vs.nbytes)
             return int(first), codec_cost(self.cfg, sp.seconds,
                                           entry.kv_bytes, float("inf"))
-        restored, t_wall = decompress_kvs([comp])
+        restored, t_wall = decompress_kvs([comp], fp16=self.takes_fp16)
         t_decompress = codec_cost(self.cfg, t_wall, entry.kv_bytes, s_dec)
         self.inject_restored(restored[0], idx)
         return int(first), t_decompress

@@ -186,3 +186,48 @@ for w, d in zip(rt.decode_workers, devs[2:]):
 print("ok")
 """, devices=4)
     assert "ok" in out
+
+
+def test_pd_identity_handoff_is_cast_on_each_decode_device():
+    """The identity hand-off restored as fp16 and cast on the device lands
+    in each decode worker's arena on that worker's device, and a 2x2
+    cluster serves the 1x1 cluster's greedy tokens."""
+    out = _run("""
+import jax, numpy as np
+import repro.serving.cluster as C
+from repro.configs import get_config
+from repro.core.profiles import IDENTITY_PROFILE
+from repro.core.quality import _scatter_kv
+from repro.models import init_params
+from repro.serving.scheduler import SchedulerConfig
+from repro.serving.workers import RuntimeConfig
+
+cfg = get_config("tiny-lm")
+params, _ = init_params(cfg, seed=3)
+C.get_reference_model = lambda: (cfg, params)   # seeded weights, no training
+devs = jax.devices()
+
+def serve(n, pdev, ddev):
+    rt = C.ClusterRuntime(
+        static_profile=IDENTITY_PROFILE, router="round_robin", n_prefill=n,
+        n_decode=n, prefill_devices=pdev, decode_devices=ddev,
+        config=RuntimeConfig(seq=32, decode_tokens=6, mode="pd",
+                             pd_inject_restored=True),
+        scheduler=SchedulerConfig(max_slots=4, max_prefills_per_step=2))
+    for i in range(8):
+        rt.submit(("qalike", "codelike", "mathlike", "summlike")[i % 4],
+                  prompt_seed=100 + i)
+    rt.run()
+    assert len(rt.completed) == 8
+    return rt, {r.rid: r.tokens.tolist() for r in rt.completed}
+
+_, one = serve(1, None, None)
+rt, two = serve(2, devs[:2], devs[2:])
+assert two == one, (one, two)
+assert _scatter_kv._cache_size() >= 1
+for w, d in zip(rt.decode_workers, devs[2:]):
+    assert all(x.devices() == {d}
+               for x in jax.tree_util.tree_leaves(w._arena)), w.name
+print("ok")
+""", devices=4)
+    assert "ok" in out
