@@ -123,7 +123,7 @@ def run_slots_sweep(smoke: bool = False,
             t0 = time.perf_counter()
             nxt, arena = arena_dec(params, arena, jnp.asarray(last[:, None]),
                                    jnp.asarray(pos), mask)
-            nxt = np.asarray(nxt)       # the iteration's single host pull
+            nxt = np.asarray(nxt)[:, 0]   # the iteration's single host pull
             arena_times.append(time.perf_counter() - t0)
             for i in range(n):
                 arena_toks[i].append(int(nxt[i]))

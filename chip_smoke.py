@@ -397,7 +397,7 @@ def placed_on(rt, prefill_devices, decode_devices) -> bool:
 
     return (all(on(w.params, d)
                 for w, d in zip(rt.prefill_workers, prefill_devices))
-            and all(on((w.params, w._arena, w._qcodes, w._qscales), d)
+            and all(on((w.params,) + w.layout.arrays, d)
                     for w, d in zip(rt.decode_workers, decode_devices)))
 
 

@@ -167,7 +167,7 @@ def _store_discard(self, tokens):
 
 def _dw_release(self, slot) -> None:
     _orig["DecodeWorker.release"](self, slot)
-    pt = getattr(self, "page_table", None)
+    pt = self.layout.page_table
     if pt is not None and pt.pages.get(slot.idx):
         raise SanitizerError(
             "leaked-pages",
@@ -179,9 +179,9 @@ def _rt_run(self, max_steps: int = 10_000):
     out = _orig["ClusterRuntime.run"](self, max_steps)
     if self.scheduler.idle:
         for dw in self.decode_workers:
-            if dw.page_table is not None:
+            if dw.layout.page_table is not None:
                 check_drained(
-                    dw.page_table,
+                    dw.layout.page_table,
                     live_slots=[s.idx for s in dw.slots.values()])
     return out
 

@@ -192,9 +192,9 @@ class CompressionPipeline:
     def decompress(self, comp: CompressedKV, fp16: bool = False) -> KVCache:
         """The float32 :class:`KVCache` of ``comp``.  With ``fp16``, an
         identity payload's K and V come back as the fp16 halves of one
-        zero-copy view of its bytes, for a consumer that casts on the
-        device (:func:`repro.core.quality.inject_kv`); every other
-        strategy restores float32 either way."""
+        zero-copy view of its bytes, for an arena that casts on the
+        device (:mod:`repro.serving.arena`); every other strategy
+        restores float32 either way."""
         cfg = comp.strategy
         if comp.identity_payload is not None:
             n = int(np.prod(comp.shape))

@@ -221,12 +221,6 @@ def _slot_copy(dst, src, slot, src_idx):
     }
 
 
-def _put_like(buf, arr):
-    """Host array ``arr`` on the device that holds ``buf``, in its dtype
-    (cast on the host, so only ``buf.dtype`` bytes cross)."""
-    return jax.device_put(np.asarray(arr, buf.dtype), buf.sharding)
-
-
 def _kv_pair(kv: KVCache) -> np.ndarray:
     """K then V as one C-ordered (2, L, H, S, D) array: a view where they
     are the two halves of one buffer, as a restored identity payload's
@@ -284,65 +278,18 @@ def inject_kv(cfg, caches, batch_idx: int, kv: KVCache):
     device that holds it: the attention layers' K and V, and the SSM
     layers' state where the hand-off carries one (:func:`inject_state`).
 
-    K and V in fp16 (a restored identity payload) cross as they are, in
-    one push, and one jitted program casts them and writes every layer's
-    row; any other KVCache is cast to the cache dtype on the host and
-    written layer by layer."""
-    if kv.k.dtype == np.float16:
-        prefix, blocks, _ = _layers_of(cfg, caches, "attn")
-        where = (prefix + blocks)[0]["k"].sharding
-        new_prefix, new_blocks = _scatter_kv(
-            prefix, blocks, jax.device_put(_kv_pair(kv), where),
-            jnp.asarray(batch_idx, jnp.int32))
-        caches = _with_layers(cfg, caches, "attn", new_prefix, new_blocks)
-    else:
-        caches = _inject_kv_host(cfg, caches, batch_idx, kv)
+    K and V cross as they are held (fp16 for a restored identity
+    payload, float32 otherwise), in one push, and one jitted program
+    casts them to the cache dtype and writes every layer's row."""
+    prefix, blocks, _ = _layers_of(cfg, caches, "attn")
+    where = (prefix + blocks)[0]["k"].sharding
+    new_prefix, new_blocks = _scatter_kv(
+        prefix, blocks, jax.device_put(_kv_pair(kv), where),
+        jnp.asarray(batch_idx, jnp.int32))
+    caches = _with_layers(cfg, caches, "attn", new_prefix, new_blocks)
     if kv.state is not None:
         caches = inject_state(cfg, caches, batch_idx, kv.state)
     return caches
-
-
-def _inject_kv_host(cfg, caches, batch_idx: int, kv: KVCache):
-    """The attention layers' K and V of ``kv`` cast to the cache dtype on
-    the host and pushed layer by layer (period layers stacked across the
-    blocks), each written by an eager scatter."""
-    from repro.models.transformer import plan_stack
-
-    plan = plan_stack(cfg)
-    upto = kv.seq
-    li = 0
-
-    def _store(buf, arr):
-        # arr (H, S, D) -> (S, H, D)
-        return buf.at[batch_idx, :upto].set(
-            _put_like(buf, arr.transpose(1, 0, 2)))
-
-    new_prefix = {}
-    for i, spec in enumerate(plan.prefix_specs):
-        name = f"layer{i}"
-        c = caches["prefix"][name]
-        if spec.kind != "attn":
-            new_prefix[name] = c
-            continue
-        new_prefix[name] = {"k": _store(c["k"], kv.k[li]),
-                            "v": _store(c["v"], kv.v[li])}
-        li += 1
-    new_blocks = dict(caches["blocks"])
-    attn_per_period = len([s for s in plan.period_specs if s.kind == "attn"])
-    for j, spec in enumerate(plan.period_specs):
-        name = f"layer{j}"
-        if spec.kind != "attn":
-            continue
-        c = caches["blocks"][name]
-        # layer indices owned by this period slot, across blocks
-        idxs = [li + n * attn_per_period for n in range(plan.n_blocks)]
-        karr = np.stack([kv.k[i2].transpose(1, 0, 2) for i2 in idxs])
-        varr = np.stack([kv.v[i2].transpose(1, 0, 2) for i2 in idxs])
-        k_buf = c["k"].at[:, batch_idx, :upto].set(_put_like(c["k"], karr))
-        v_buf = c["v"].at[:, batch_idx, :upto].set(_put_like(c["v"], varr))
-        new_blocks[name] = {"k": k_buf, "v": v_buf}
-        li += 1
-    return {"prefix": new_prefix, "blocks": new_blocks}
 
 
 @jax.jit
@@ -398,317 +345,6 @@ def _hold_parked_state(cfg, new, old, mask):
 
 
 # ---------------------------------------------------------------------------
-# Paged decode arena (DESIGN.md §12)
-# ---------------------------------------------------------------------------
-def init_paged_pools(cfg, num_pages: int, page_size: int, group: int):
-    """Build the paged arena's device pools: ``(pool, qcodes, qscales)``.
-
-    ``pool`` mirrors ``init_cache``'s pytree with the (batch, max_len)
-    leading axes replaced by (num_pages, page_size) — logical position
-    ``t`` of a slot lives at row ``t % page_size`` of the pool page named
-    by entry ``t // page_size`` of its block table.  ``qcodes``/
-    ``qscales`` are the parallel quantized pools (int8 codes + f32
-    scales, one scale per ``group`` channels per token) sharing the SAME
-    page ids: a page holds either fp content or quantized content, and
-    the per-slot ``quant_len`` decides which pool each position reads
-    from.  Page 0 is the reserved scratch page (never allocated)."""
-    from repro.models import init_cache
-
-    pool = init_cache(cfg, num_pages, max_len=page_size)
-    qcodes = jax.tree_util.tree_map(
-        lambda a: jnp.zeros(a.shape, jnp.int8), pool)
-    qscales = jax.tree_util.tree_map(
-        lambda a: jnp.zeros(a.shape[:-1] + (a.shape[-1] // group,),
-                            jnp.float32), pool)
-    return pool, qcodes, qscales
-
-
-def _paged_view(leaf, bt, prefix: bool):
-    """Gather a pool leaf into the dense (·, B, S, H, D) decode view."""
-    if prefix:  # (P, ps, H, D) -> (B, PPS*ps, H, D)
-        g = jnp.take(leaf, bt, axis=0)
-        return g.reshape(g.shape[0], -1, *g.shape[3:])
-    g = jnp.take(leaf, bt, axis=1)  # (n, B, PPS, ps, H, D)
-    return g.reshape(g.shape[0], g.shape[1], -1, *g.shape[4:])
-
-
-def _blend_quant(view, qc_view, qs_view, quant_len, prefix: bool):
-    """Dequantize the quant-pool view and take it for positions below
-    each slot's ``quant_len`` (exactly the ``group_dequantize`` math:
-    signed codes x f32 scale, then cast to the cache compute dtype)."""
-    d = view.shape[-1]
-    g = d // qs_view.shape[-1]
-    x = qc_view.astype(jnp.float32).reshape(qc_view.shape[:-1] + (d // g, g))
-    x = (x * qs_view[..., None].astype(jnp.float32)
-         ).reshape(qc_view.shape).astype(view.dtype)
-    s = view.shape[1] if prefix else view.shape[2]
-    use_q = jnp.arange(s, dtype=jnp.int32)[None, :] < quant_len[:, None]
-    m = use_q[:, :, None, None] if prefix else use_q[None, :, :, None, None]
-    return jnp.where(m, x, view)
-
-
-def _pad_axis(x, target: int, axis: int):
-    cur = x.shape[axis]
-    if cur >= target:
-        return jax.lax.slice_in_dim(x, 0, target, axis=axis)
-    pad = [(0, 0)] * x.ndim
-    pad[axis] = (0, target - cur)
-    return jnp.pad(x, pad)
-
-
-@lru_cache(maxsize=8)
-def _paged_steps(cfg_name: str, page_size: int):
-    """Jitted paged-arena kernels for one model config: ``(arena, copy)``.
-
-    ``arena(params, pool, qcodes, qscales, bt, quant_len, tokens, pos,
-    mask)`` is the paged analogue of ``_jitted_steps``'s arena decode:
-    gather every slot's pages into a contiguous view (dequant-blending
-    quantized-resident positions), run one masked ``decode_step``, then
-    scatter ONLY the newly written K/V row back to each slot's page.
-    Parked rows (mask False) are pinned to the view's last position,
-    which maps to the scratch page or the slot's own never-attended tail
-    row, so their writes are inert — same contract as the dense arena.
-    Block tables and lengths are traced: page churn never recompiles.
-
-    ``copy(pool, src, bt_row, src_idx)`` is ``copy_cache_slot`` as a
-    page-map operation: one prefilled source row lands in the slot's
-    owned pages (sentinel-0 tail entries spill into scratch).
-    """
-    from repro.models import decode_step
-
-    cfg = get_config(cfg_name)
-
-    def arena(params, pool, qcodes, qscales, bt, quant_len, tokens, pos,
-              mask):
-        view_len = bt.shape[1] * page_size
-        pos = jnp.where(mask, pos, view_len - 1).astype(jnp.int32)
-
-        def build(prefix):
-            def f(p, qc, qs):
-                return _blend_quant(_paged_view(p, bt, prefix),
-                                    _paged_view(qc, bt, prefix),
-                                    _paged_view(qs, bt, prefix),
-                                    quant_len, prefix)
-            return f
-
-        caches = {
-            "prefix": jax.tree_util.tree_map(
-                build(True), pool["prefix"], qcodes["prefix"],
-                qscales["prefix"]),
-            "blocks": jax.tree_util.tree_map(
-                build(False), pool["blocks"], qcodes["blocks"],
-                qscales["blocks"]),
-        }
-        logits, new_caches = decode_step(cfg, params, caches, tokens, pos)
-        nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-
-        page_idx = jnp.take_along_axis(
-            bt, (pos // page_size)[:, None], axis=1)[:, 0]
-        offset = pos % page_size
-
-        def scat(prefix):
-            def f(p, nv):
-                if prefix:
-                    row = jnp.take_along_axis(
-                        nv, pos[:, None, None, None], axis=1)[:, 0]
-                    return p.at[page_idx, offset].set(row.astype(p.dtype))
-                row = jnp.take_along_axis(
-                    nv, pos[None, :, None, None, None], axis=2)[:, :, 0]
-                return p.at[:, page_idx, offset].set(row.astype(p.dtype))
-            return f
-
-        new_pool = {
-            "prefix": jax.tree_util.tree_map(
-                scat(True), pool["prefix"], new_caches["prefix"]),
-            "blocks": jax.tree_util.tree_map(
-                scat(False), pool["blocks"], new_caches["blocks"]),
-        }
-        return jnp.where(mask, nxt, 0), new_pool
-
-    def copy(pool, src, bt_row, src_idx):
-        pps = bt_row.shape[0]
-
-        def w_prefix(p, s):
-            row = jax.lax.dynamic_slice_in_dim(s, src_idx, 1, 0)[0]
-            row = _pad_axis(row, pps * page_size, axis=0)
-            pages = row.reshape(pps, page_size, *row.shape[1:])
-            return p.at[bt_row].set(pages.astype(p.dtype))
-
-        def w_block(p, s):
-            row = jax.lax.dynamic_slice_in_dim(s, src_idx, 1, 1)[:, 0]
-            row = _pad_axis(row, pps * page_size, axis=1)
-            pages = row.reshape(row.shape[0], pps, page_size,
-                                *row.shape[2:])
-            return p.at[:, bt_row].set(pages.astype(p.dtype))
-
-        return {
-            "prefix": jax.tree_util.tree_map(w_prefix, pool["prefix"],
-                                             src["prefix"]),
-            "blocks": jax.tree_util.tree_map(w_block, pool["blocks"],
-                                             src["blocks"]),
-        }
-
-    return jax.jit(arena), jax.jit(copy)
-
-
-@lru_cache(maxsize=16)
-def _paged_verify_steps(cfg_name: str, page_size: int, width: int):
-    """Jitted paged multi-token verify step (DESIGN.md §15).
-
-    ``verify(params, pool, qcodes, qscales, bt, quant_len, tokens, pos,
-    mask)`` is ``_paged_steps``'s arena decode widened to a ``(B, width)``
-    query block: each live slot feeds ``width`` tokens at consecutive
-    positions ``pos..pos+width-1`` and gets all ``width`` greedy argmax
-    outputs back for host-side accept-prefix matching.  All ``width`` K/V
-    rows are scattered to each slot's pages; positions beyond a slot's
-    *ensured* page span map to block-table entry 0 — the reserved scratch
-    page, which no live query ever reads — so slots verifying fewer than
-    ``width-1`` drafts need no masking: their surplus writes are inert by
-    construction.  Parked rows pin to ``view_len - width`` (scratch pages
-    again).  Rejected suffixes are rolled back by the caller via
-    ``PageTable.release_tail``; the pages themselves need no scrubbing
-    because reads are capped at each slot's committed ``pos``.
-    """
-    from repro.models import decode_step
-
-    cfg = get_config(cfg_name)
-
-    def verify(params, pool, qcodes, qscales, bt, quant_len, tokens, pos,
-               mask):
-        view_len = bt.shape[1] * page_size
-        pos = jnp.where(mask, pos, view_len - width).astype(jnp.int32)
-
-        def build(prefix):
-            def f(p, qc, qs):
-                return _blend_quant(_paged_view(p, bt, prefix),
-                                    _paged_view(qc, bt, prefix),
-                                    _paged_view(qs, bt, prefix),
-                                    quant_len, prefix)
-            return f
-
-        caches = {
-            "prefix": jax.tree_util.tree_map(
-                build(True), pool["prefix"], qcodes["prefix"],
-                qscales["prefix"]),
-            "blocks": jax.tree_util.tree_map(
-                build(False), pool["blocks"], qcodes["blocks"],
-                qscales["blocks"]),
-        }
-        logits, new_caches = decode_step(cfg, params, caches, tokens, pos)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (B, width)
-
-        new_pool = pool
-        for j in range(width):
-            pj = pos + j
-            page_idx = jnp.take_along_axis(
-                bt, (pj // page_size)[:, None], axis=1)[:, 0]
-            offset = pj % page_size
-
-            def scat(prefix, pj=pj, page_idx=page_idx, offset=offset):
-                def f(p, nv):
-                    if prefix:
-                        row = jnp.take_along_axis(
-                            nv, pj[:, None, None, None], axis=1)[:, 0]
-                        return p.at[page_idx, offset].set(row.astype(p.dtype))
-                    row = jnp.take_along_axis(
-                        nv, pj[None, :, None, None, None], axis=2)[:, :, 0]
-                    return p.at[:, page_idx, offset].set(row.astype(p.dtype))
-                return f
-
-            new_pool = {
-                "prefix": jax.tree_util.tree_map(
-                    scat(True), new_pool["prefix"], new_caches["prefix"]),
-                "blocks": jax.tree_util.tree_map(
-                    scat(False), new_pool["blocks"], new_caches["blocks"]),
-            }
-        return jnp.where(mask[:, None], nxt, 0), new_pool
-
-    return jax.jit(verify)
-
-
-def copy_cache_slot_paged(cfg, pool, src, bt_row, page_size: int,
-                          src_idx: int = 0):
-    """Paged ``copy_cache_slot``: land one prefilled source row in the
-    pages of ``bt_row`` (a (PPS,) int32 row; 0 entries spill to scratch)."""
-    if "self" in pool:
-        raise NotImplementedError("paged arena: decoder-only caches")
-    _, copy = _paged_steps(cfg.name, page_size)
-    return copy(pool, src, jnp.asarray(bt_row, jnp.int32),
-                jnp.asarray(src_idx, jnp.int32))
-
-
-def _paged_scatter(cfg, pool, bt_row, k_arr, v_arr, upto: int,
-                   page_size: int):
-    """Scatter per-layer (L, H, S, X) k/v arrays into a slot's pages —
-    the page-map core of ``inject_kv_paged``/``inject_quant_pages``.
-    Only the first ``ceil(upto / page_size)`` owned pages are written
-    (partial-page tails are zero-filled; the slot is fresh, so nothing
-    real is clobbered).  The arrays move to the pool's device first."""
-    from repro.models.transformer import plan_stack
-
-    plan = plan_stack(cfg)
-    n_used = -(-upto // page_size)
-    rows = jnp.asarray(np.asarray(bt_row)[:n_used], jnp.int32)
-    where = jax.tree_util.tree_leaves(pool)[0].sharding
-    li = 0
-
-    def _pages(arr):  # (H, S, X) -> (n_used, ps, H, X)
-        a = jax.device_put(arr, where).swapaxes(0, 1)  # (S, H, X)
-        a = _pad_axis(a, n_used * page_size, axis=0)
-        return a.reshape(n_used, page_size, *a.shape[1:])
-
-    new_prefix = {}
-    for i, spec in enumerate(plan.prefix_specs):
-        name = f"layer{i}"
-        c = pool["prefix"][name]
-        if spec.kind != "attn":
-            new_prefix[name] = c
-            continue
-        new_prefix[name] = {
-            "k": c["k"].at[rows].set(_pages(k_arr[li]).astype(c["k"].dtype)),
-            "v": c["v"].at[rows].set(_pages(v_arr[li]).astype(c["v"].dtype)),
-        }
-        li += 1
-    new_blocks = dict(pool["blocks"])
-    attn_per_period = len([s for s in plan.period_specs if s.kind == "attn"])
-    for j, spec in enumerate(plan.period_specs):
-        name = f"layer{j}"
-        if spec.kind != "attn":
-            continue
-        c = pool["blocks"][name]
-        idxs = [li + n * attn_per_period for n in range(plan.n_blocks)]
-        karr = jnp.stack([_pages(k_arr[i2]) for i2 in idxs])
-        varr = jnp.stack([_pages(v_arr[i2]) for i2 in idxs])
-        new_blocks[name] = {
-            "k": c["k"].at[:, rows].set(karr.astype(c["k"].dtype)),
-            "v": c["v"].at[:, rows].set(varr.astype(c["v"].dtype)),
-        }
-        li += 1
-    return {"prefix": new_prefix, "blocks": new_blocks}
-
-
-def inject_kv_paged(cfg, pool, bt_row, kv: KVCache, page_size: int):
-    """Paged ``inject_kv``: write a restored KVCache into a fresh slot's
-    pages as a page-map operation."""
-    return _paged_scatter(cfg, pool, bt_row, kv.k, kv.v, kv.seq, page_size)
-
-
-def inject_quant_pages(cfg, qcodes, qscales, bt_row, k_codes, k_scales,
-                       v_codes, v_scales, upto: int, page_size: int):
-    """Land packed quantized KV straight in the quant page pools — the
-    zero-materialization injection path for paged-eligible strategies.
-    ``k_codes``/``v_codes`` are (L, H, S, D) signed int8;
-    ``k_scales``/``v_scales`` are (L, H, S, D/group) f32 (already
-    round-tripped through fp16, so the fused dequant is bit-identical
-    to the materialized ``group_dequantize`` + inject path)."""
-    new_qc = _paged_scatter(cfg, qcodes, bt_row, k_codes, v_codes, upto,
-                            page_size)
-    new_qs = _paged_scatter(cfg, qscales, bt_row, k_scales, v_scales, upto,
-                            page_size)
-    return new_qc, new_qs
-
-
-# ---------------------------------------------------------------------------
 # Quality evaluation
 # ---------------------------------------------------------------------------
 @lru_cache(maxsize=8)
@@ -716,15 +352,21 @@ def _jitted_steps(cfg_name: str, seq: int, batch: int, max_len: int):
     """Returns (prefill, decode, arena_decode), all jitted.
 
     ``arena_decode(params, caches, tokens, pos, mask)`` is the masked
-    batched decode of the slot arena (DESIGN.md §9): ``tokens`` (B, 1),
-    ``pos`` (B,) per-slot next cache positions, ``mask`` (B,) live-slot
-    flags.  Every slot advances in ONE model call; parked rows (mask
-    False — free slots and this iteration's fresh prefills) are pinned to
-    the scratch position ``max_len - 1``, which no live query position
-    ever attends to, so their cache writes are inert, and an SSM layer's
+    batched step of the dense slot arena (DESIGN.md §9, §15): ``tokens``
+    (B, w), ``pos`` (B,) per-slot next cache positions, ``mask`` (B,)
+    live-slot flags.  Every slot feeds its ``w`` tokens at positions
+    ``pos..pos+w-1`` in ONE model call and gets the ``w`` greedy argmax
+    outputs back, (B, w): plain decode is ``w = 1``, a speculative
+    verify ``w = 1 + drafts``; the width is the token block's static
+    shape, so jit compiles once per width.  Parked rows (mask False —
+    free slots and this iteration's fresh prefills) are pinned to the
+    scratch rows from ``max_len - w``, which no live query position ever
+    attends to, so their cache writes are inert, and an SSM layer's
     state of a parked row is kept bit for bit (``where(mask, new, old)``).
-    The next token per slot comes from an on-device argmax; the caller
-    pulls the (B,) token vector back once per iteration.
+    Rows a live slot writes past its committed position (rejected
+    drafts) are never read: reads stop at each slot's position, and
+    later steps overwrite them.  The caller pulls the (B, w) tokens back
+    once per iteration.
     """
     from repro.models import decode_step, prefill
 
@@ -733,40 +375,13 @@ def _jitted_steps(cfg_name: str, seq: int, batch: int, max_len: int):
     dec = jax.jit(lambda p, c, t, pos: decode_step(cfg, p, c, t, pos))
 
     def _arena(p, c, t, pos, mask):
-        pos = jnp.where(mask, pos, max_len - 1).astype(jnp.int32)
+        pos = jnp.where(mask, pos, max_len - t.shape[1]).astype(jnp.int32)
         logits, new = decode_step(cfg, p, c, t, pos)
-        nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-        return jnp.where(mask, nxt, 0), _hold_parked_state(cfg, new, c, mask)
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return (jnp.where(mask[:, None], nxt, 0),
+                _hold_parked_state(cfg, new, c, mask))
 
     return pre, dec, jax.jit(_arena)
-
-
-@lru_cache(maxsize=16)
-def _verify_steps(cfg_name: str, max_len: int, width: int):
-    """Jitted dense multi-token verify step (DESIGN.md §15).
-
-    ``verify(params, caches, tokens, pos, mask)`` widens ``_jitted_steps``'s
-    arena decode to a ``(B, width)`` query block: each live slot feeds its
-    last committed token plus ``width-1`` draft tokens at consecutive
-    positions ``pos..pos+width-1`` and receives all ``width`` greedy argmax
-    outputs for host-side accept-prefix matching.  Parked rows pin to
-    ``max_len - width`` so every one of their ``width`` K/V row writes
-    stays in-bounds; the writes are inert because rows are per-slot and
-    reads are capped at each slot's committed position (``kv_valid``), so
-    garbage beyond ``pos`` — including rejected draft KV — is simply
-    overwritten by later steps and never attended to.
-    """
-    from repro.models import decode_step
-
-    cfg = get_config(cfg_name)
-
-    def _verify(p, c, t, pos, mask):
-        pos = jnp.where(mask, pos, max_len - width).astype(jnp.int32)
-        logits, c = decode_step(cfg, p, c, t, pos)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (B, width)
-        return jnp.where(mask[:, None], nxt, 0), c
-
-    return jax.jit(_verify)
 
 
 def _prompts_for(workload: str, n: int, seq: int, seed: int

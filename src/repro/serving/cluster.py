@@ -451,7 +451,7 @@ class ClusterRuntime:
             workload=req.workload, bandwidth=bandwidth, t_slo=req.t_slo,
             q_min=req.q_min, t_model=t_model, kv_bytes=req.kv_bytes,
             slo_metric=req.resolved_slo_metric(self.slo_metric_default),
-            route=route_id, fused_dec=self.cfg.paged)
+            route=route_id, fused_dec=dw.layout.fused_dequant)
         predict = getattr(self.controller, "predict", None)
         if predict is not None:
             t = predict(ctx)
@@ -503,12 +503,9 @@ class ClusterRuntime:
         # quantized pages and decode in the fused attention kernel — the
         # fetch option drops its V/s_dec term (DESIGN.md §12).
         def _fused(strategy) -> bool:
-            if not self.cfg.paged:
-                return False
-            from repro.core.strategy import paged_eligible
             comp = e.payload[0]
             head_dim = comp.shape[3] if hasattr(comp, "shape") else None
-            return paged_eligible(strategy, head_dim=head_dim)
+            return dw.layout.fuses(strategy, head_dim)
 
         stored = TierFetch(variant="stored", wire_bytes=e.wire_bytes,
                            s_dec=e.payload[2],
@@ -673,7 +670,7 @@ class ClusterRuntime:
         # profile.s_dec, so running it would be pure benchmark tax).
         if self.cfg.pd_inject_restored or self.cfg.prefill_tok_s is None:
             restored, t_wall = decompress_kvs(
-                [comp], fp16=self.cfg.pd_inject_restored and dw.takes_fp16)
+                [comp], fp16=self.cfg.pd_inject_restored)
         else:
             restored, t_wall = None, 0.0
         t_decompress = codec_cost(self.cfg, t_wall, ctx.kv_bytes,

@@ -185,11 +185,11 @@ class ModelDraft:
         if k_max <= 0:
             return {idx: [] for idx, _, _, _ in items}
         mask = np.zeros(self.n_slots, bool)
-        toks = np.zeros(self.n_slots, np.int32)
+        toks = np.zeros((self.n_slots, 1), np.int32)
         pos = np.zeros(self.n_slots, np.int32)
         for idx, _rid, last_tok, p in items:
             mask[idx] = True
-            toks[idx] = last_tok
+            toks[idx, 0] = last_tok
             pos[idx] = p
             self._positions[idx] = p
         jmask = jnp.asarray(mask)
@@ -198,13 +198,13 @@ class ModelDraft:
         # draft's KV row (its output is discarded).
         for step in range(k_max + 1):
             nxt, self._caches = arena(
-                self.model.params, self._caches, jnp.asarray(toks[:, None]),
+                self.model.params, self._caches, jnp.asarray(toks),
                 jnp.asarray(pos + step), jmask)
             # lint: sync-ok(draft-side proposal pull - the k+1 small host reads per verify step are the two-model path's documented cost)
             nxt = np.asarray(nxt)
             if step < k_max:
                 for idx, _rid, _lt, _p in items:
                     if step < k.get(idx, 0):
-                        proposals[idx].append(int(nxt[idx]))
+                        proposals[idx].append(int(nxt[idx, 0]))
             toks = nxt
         return proposals

@@ -13,8 +13,8 @@ holds the two worker types that
   worker serialize on it (the ``busy`` offset); requests on different
   workers run concurrently.
 * :class:`DecodeWorker` — one decode engine: its own fixed-capacity slot
-  arena (ONE cache pytree with a leading slot axis, advanced by a single
-  masked jitted decode per iteration), its own local slot-id pool, and its
+  arena in one layout (:mod:`repro.serving.arena`, advanced by a single
+  masked jitted step per iteration), its own local slot-id pool, and its
   own decode-side KV tier hierarchy (HBM/DRAM are worker-local; the remote
   pool tier may be shared cluster-wide — see
   :class:`~repro.serving.kvstore.TieredKVStore`).
@@ -40,26 +40,17 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.controller import Decision, ServiceAwareController, ServiceContext
-from repro.core import codecs
-from repro.core.kvcache import PageTable, state_nbytes
+from repro.core.kvcache import state_nbytes
 from repro.core.pipeline import CompressedKV, CompressionPipeline
 from repro.core.profiles import Profile
-from repro.core.quality import (
-    DeviceKV,
-    _jitted_steps,
-    _paged_steps,
-    copy_cache_slot,
-    copy_cache_slot_paged,
-    init_paged_pools,
-    inject_kv,
-    inject_kv_paged,
-    inject_quant_pages,
-)
-from repro.core.strategy import StrategyConfig, paged_eligible
+from repro.core.quality import DeviceKV, _jitted_steps
+from repro.core.strategy import StrategyConfig
 from repro.models.layers import COMPUTE_DTYPE
 from repro.serving import tracing
+from repro.serving.arena import DenseArena, PagedArena
 from repro.serving.kvstore import TierSpec
 from repro.serving.request import Request
+from repro.serving.speculative import accept_length
 
 # The arena holds keys and values in the compute type.
 KV_ITEMSIZE = np.dtype(COMPUTE_DTYPE).itemsize
@@ -100,46 +91,14 @@ def compress_kvs(strategy: StrategyConfig, kvs: Sequence[Any]
 def decompress_kvs(comps: Sequence[CompressedKV], fp16: bool = False
                    ) -> Tuple[List[Any], float]:
     """Restore wire payloads to KV.  Returns ``(kvs, measured_seconds)``.
-    ``fp16`` only for a dense arena's injection: identity payloads then
-    stay fp16 views (:meth:`CompressionPipeline.decompress`)."""
+    ``fp16`` for an arena's injection: identity payloads then stay fp16
+    views (:meth:`CompressionPipeline.decompress`)."""
     with tracing.timed("restore") as sp:
         kvs = [CompressionPipeline(c.strategy).decompress(c, fp16)
                for c in comps]
         if sp:
             sp.add(bytes=sum(kv.k.nbytes + kv.v.nbytes for kv in kvs))
     return kvs, sp.seconds
-
-
-def quant_entry_arrays(comp: CompressedKV):
-    """Unpack a paged-eligible :class:`CompressedKV` into page-pool form:
-    ``((k_codes, k_scales), (v_codes, v_scales))`` with codes (L, H, S, D)
-    signed int8 and scales (L, H, S, D) per-channel f32 (the stored fp16
-    group scale broadcast across its group — numerically identical to the
-    grouped multiply, so the fused dequant is bit-for-bit equal to
-    ``group_dequantize`` + materialized injection).
-
-    Only valid when ``paged_eligible(comp.strategy)``: one symmetric
-    per-token bucket per tensor, codec "none", no transform."""
-    L, H, S, D = comp.shape
-    out = []
-    for wires in (comp.k_buckets, comp.v_buckets):
-        assert len(wires) == 1, "paged-eligible strategies are single-bucket"
-        w = wires[0]
-        count = int(np.prod(w.codes_shape))
-        codes = codecs.decode_codes(w.payload, w.bits, count,
-                                    comp.strategy.codec)
-        codes = codes.reshape(w.codes_shape)          # (N, S, D) uint8
-        signed = (codes.astype(np.int16)
-                  - (1 << (w.bits - 1))).astype(np.int8)
-        sc = w.scale.astype(np.float32)[..., 0]       # (N, S, D/group)
-        sc = np.repeat(sc, w.group_size, axis=2)[:, :, :D]
-        arr = np.zeros((L, H, S, D), np.int8)
-        sarr = np.zeros((L, H, S, D), np.float32)
-        ls, hs = w.lh_index[:, 0], w.lh_index[:, 1]
-        arr[ls, hs] = signed
-        sarr[ls, hs] = sc
-        out.append((arr, sarr))
-    return out[0], out[1]
 
 
 def recompress_entry(entry, profile: Profile) -> Optional[Tuple[Any, int]]:
@@ -477,8 +436,8 @@ class PrefillWorker(_Placed):
 # Decode worker
 # ---------------------------------------------------------------------------
 class DecodeWorker(_Placed):
-    """One decode engine of the cluster: a fixed-capacity slot arena (ONE
-    cache pytree, leading axis ``n_slots``), a LIFO local slot-id pool,
+    """One decode engine of the cluster: a fixed-capacity slot arena in
+    one layout (:mod:`repro.serving.arena`), a LIFO local slot-id pool,
     and the worker's decode-side KV tier hierarchy."""
 
     def __init__(self, wid: int, model: ModelHandle, cfg: RuntimeConfig,
@@ -496,32 +455,32 @@ class DecodeWorker(_Placed):
         # LIFO so a hot slot's cache row is reused first (same recycling
         # discipline the scheduler used when it owned the slot ids).
         self.free_slots: List[int] = list(range(n_slots))[::-1]
-        self._dec_arena = None
-        self._arena: Any = None          # cache pytree, leading axis n_slots
+        if cfg.paged:
+            self.layout = PagedArena(model, n_slots, self.max_len,
+                                     cfg.page_size, cfg.arena_pages, device)
+        else:
+            self.layout = DenseArena(model, n_slots, self.max_len, cfg.seq,
+                                     device)
         self._positions = np.zeros(n_slots, np.int32)  # next write pos
         self._last_tok = np.zeros(n_slots, np.int32)   # last emitted tok
         self.decode_steps = 0            # lifetime arena decode calls
-        # Paged-arena state (cfg.paged; DESIGN.md §12).  The fp pool
-        # replaces the dense arena in self._arena; the parallel quant
-        # pools hold packed pages for fused-dequant decode, valid per
-        # slot below its _quant_len watermark.
-        self.page_table: Optional[PageTable] = None
-        self._qcodes: Any = None
-        self._qscales: Any = None
-        self._quant_len = np.zeros(n_slots, np.int32)
         # Speculative decode state (DESIGN.md §15): the draft proposer
         # (built lazily when a speculative slot first lands) and the
         # worker-lifetime accept tallies behind the benchmark's
         # tokens-per-step metric.
         self._draft: Any = None
-        self._verify_fns: Dict[int, Any] = {}   # width -> jitted verify
         self.verify_steps = 0
         self.spec_committed = 0
 
     @property
-    def _pps(self) -> int:
-        """Block-table row length: pages per worst-case slot."""
-        return -(-self.max_len // self.cfg.page_size)
+    def _arena(self):
+        """The layout's device cache pytree (leading axis ``n_slots``, or
+        ``num_pages`` for the paged layout)."""
+        return self.layout.cache
+
+    @_arena.setter
+    def _arena(self, cache) -> None:
+        self.layout.cache = cache
 
     # ------------------------------------------------------------------
     @property
@@ -535,53 +494,24 @@ class DecodeWorker(_Placed):
     # ------------------------------------------------------------------
     def ensure_arena(self):
         if self._arena is None:
-            from repro.models.transformer import init_cache, plan_stack
+            from repro.models.transformer import plan_stack
             plan = plan_stack(self.model.cfg)
-            if ((self.cfg.paged or self.cfg.spec_k > 0)
+            if ((not self.layout.holds_state or self.cfg.spec_k > 0)
                     and any(s.kind != "attn"
                             for s in plan.prefix_specs + plan.period_specs)):
                 raise NotImplementedError(
                     "SSM layers run in the dense arena's one-token step "
                     "only: the paged arena and the speculative verify step "
                     "hold no recurrent state per slot")
-            # Allocated on the worker's device directly: never staged
-            # through the default device, which may be another worker's.
-            with jax.default_device(self.device):
-                if self.cfg.paged:
-                    num_pages = (self.cfg.arena_pages
-                                 or self.n_slots * self._pps + 1)
-                    self.page_table = PageTable(num_pages,
-                                                self.cfg.page_size)
-                    # Per-channel scale layout in the sim pools (group=1):
-                    # any strategy group maps onto it by broadcasting its
-                    # group scale, so one pool serves every eligible
-                    # profile.
-                    self._arena, self._qcodes, self._qscales = (
-                        init_paged_pools(self.model.cfg, num_pages,
-                                         self.cfg.page_size, group=1))
-                else:
-                    self._arena = init_cache(self.model.cfg, self.n_slots,
-                                             self.max_len)
+            self.layout.allocate()
         return self._arena
 
     def _arena_fn(self):
-        if self._dec_arena is None:
-            if self.cfg.paged:
-                self._dec_arena, _ = _paged_steps(self.model.cfg.name,
-                                                  self.cfg.page_size)
-            else:
-                _, _, self._dec_arena = _jitted_steps(
-                    self.model.cfg.name, self.cfg.seq, self.n_slots,
-                    self.max_len)
-        return self._dec_arena
+        """The layout's jitted step, which :meth:`decode_iteration` calls
+        with the layout's operands."""
+        return self.layout.program()
 
     # ------------------------------------------------------------------
-    def _block_tables(self) -> np.ndarray:
-        bt = np.zeros((self.n_slots, self._pps), np.int32)
-        for s, owned in self.page_table.pages.items():
-            bt[s, :len(owned)] = owned
-        return bt
-
     def copy_from_caches(self, caches, idx: int) -> None:
         """Materialize arena row ``idx`` from a prefill worker's batch-1
         cache (the cold path's slot hand-off; the cache moves from the
@@ -589,82 +519,48 @@ class DecodeWorker(_Placed):
         crosses from the host."""
         with tracing.span("inject") as sp:
             self.ensure_arena()
-            caches = self._put(caches)
-            if self.cfg.paged:
-                self.page_table.ensure(idx, self.cfg.seq)
-                row = self.page_table.block_row(idx, self._pps)
-                self._arena = copy_cache_slot_paged(
-                    self.model.cfg, self._arena, caches, row,
-                    self.cfg.page_size)
-                self._quant_len[idx] = 0
-            else:
-                self._arena = copy_cache_slot(self.model.cfg, self._arena,
-                                              caches, idx)
+            self.layout.land_prefill(self._put(caches), idx, self.cfg.seq)
             if sp:
                 sp.add(bytes=0)
 
-    @property
-    def takes_fp16(self) -> bool:
-        """Whether :meth:`inject_restored` casts an fp16 restore on the
-        device: the dense arena does; the paged arena takes float32."""
-        return not self.cfg.paged
-
     def inject_restored(self, kv, idx: int) -> None:
         """Materialize arena row ``idx`` from a wire-restored KV and its
-        recurrent state, if any (pushed as held; span ``state_inject``
-        inside ``inject``).  An fp16 restore into the dense arena crosses
-        as it is and is cast on the device (counter ``device_cast`` 1);
-        any other is cast to the arena's dtype on the host (0)."""
+        recurrent state, if any: K and V are pushed as held (fp16 for an
+        identity restore) and cast on the device (span ``state_inject``
+        inside ``inject``)."""
         with tracing.span("inject") as sp:
             self.ensure_arena()
-            if self.cfg.paged:
-                self.page_table.ensure(idx, kv.seq)
-                row = self.page_table.block_row(idx, self._pps)
-                self._arena = inject_kv_paged(self.model.cfg, self._arena,
-                                              row, kv, self.cfg.page_size)
-                self._quant_len[idx] = 0
-            else:
-                self._arena = inject_kv(self.model.cfg, self._arena, idx,
-                                        kv)
+            self.layout.inject(kv, idx)
             if sp:
                 n_state = state_nbytes(kv.state)
                 sp.add(bytes=(kv.k.size + kv.v.size) * KV_ITEMSIZE + n_state,
-                       state_bytes=n_state,
-                       device_cast=int(self.takes_fp16
-                                       and kv.k.dtype == np.float16))
+                       state_bytes=n_state)
 
     def fetch_entry(self, entry, idx: int) -> Tuple[int, float]:
         """Land a stored pool entry in arena slot ``idx``.  Returns
         ``(first_token, t_decompress)``.
 
-        Paged arena + paged-eligible stored strategy: the packed codes
-        and fp16 group scales scatter STRAIGHT into the quantized page
-        pools — no fp16 materialization, so the decompress stage leaves
-        the TTFT critical path (the fused dequant runs inside decode
-        attention; under the virtual clock the remaining adapter cost
-        models as V/inf = 0).  Everything else decompresses and injects
-        fp16 pages/rows as before.  Cache injection is host-side
-        bookkeeping of the miniature (the cold path's equivalent writes
-        happen inside prefill), so it is not billed to the virtual
+        A layout that fuses dequant (paged) takes a paged-eligible stored
+        strategy's packed codes and fp16 group scales STRAIGHT into its
+        quantized page pools — no fp16 materialization, so the decompress
+        stage leaves the TTFT critical path (the fused dequant runs inside
+        the decode step; under the virtual clock the remaining adapter
+        cost models as V/inf = 0).  Everything else decompresses (an
+        identity payload as fp16) and injects.  Cache injection is
+        host-side bookkeeping of the miniature (the cold path's equivalent
+        writes happen inside prefill), so it is not billed to the virtual
         clock."""
         comp, first, s_dec = entry.payload
-        if (self.cfg.paged and isinstance(comp, CompressedKV)
-                and paged_eligible(comp.strategy, head_dim=comp.shape[3])):
+        if (isinstance(comp, CompressedKV)
+                and self.layout.fuses(comp.strategy, comp.shape[3])):
             with tracing.timed("restore") as sp:
-                (kc, ks), (vc, vs) = quant_entry_arrays(comp)
                 self.ensure_arena()
-                seq = comp.shape[2]
-                self.page_table.ensure(idx, seq)
-                row = self.page_table.block_row(idx, self._pps)
-                self._qcodes, self._qscales = inject_quant_pages(
-                    self.model.cfg, self._qcodes, self._qscales, row,
-                    kc, ks, vc, vs, seq, self.cfg.page_size)
-                self._quant_len[idx] = seq
+                nbytes = self.layout.land_pages(comp, idx)
                 if sp:
-                    sp.add(bytes=kc.nbytes + ks.nbytes + vc.nbytes + vs.nbytes)
+                    sp.add(bytes=nbytes)
             return int(first), codec_cost(self.cfg, sp.seconds,
                                           entry.kv_bytes, float("inf"))
-        restored, t_wall = decompress_kvs([comp], fp16=self.takes_fp16)
+        restored, t_wall = decompress_kvs([comp], fp16=True)
         t_decompress = codec_cost(self.cfg, t_wall, entry.kv_bytes, s_dec)
         self.inject_restored(restored[0], idx)
         return int(first), t_decompress
@@ -681,20 +577,6 @@ class DecodeWorker(_Placed):
                 self._draft = NGramDraft()
         return self._draft
 
-    def _verify_fn(self, width: int):
-        """Jitted multi-token verify for ``width`` (one compile per
-        speculation width; the per-slot accept length stays traced)."""
-        fn = self._verify_fns.get(width)
-        if fn is None:
-            from repro.core.quality import _paged_verify_steps, _verify_steps
-            if self.cfg.paged:
-                fn = _paged_verify_steps(self.model.cfg.name,
-                                         self.cfg.page_size, width)
-            else:
-                fn = _verify_steps(self.model.cfg.name, self.max_len, width)
-            self._verify_fns[width] = fn
-        return fn
-
     def occupy(self, slot: Slot, first: int,
                prompt: Optional[Sequence[int]] = None) -> None:
         self.slots[slot.req.rid] = slot
@@ -706,22 +588,21 @@ class DecodeWorker(_Placed):
     def release(self, slot: Slot) -> None:
         self.free_slots.append(slot.idx)
         del self.slots[slot.req.rid]
-        if self.cfg.paged and self.page_table is not None:
-            self.page_table.release(slot.idx)
-            self._quant_len[slot.idx] = 0
+        self.layout.release(slot.idx)
         if slot.spec_k > 0 and self._draft is not None:
             self._draft.stop(slot.idx, slot.req.rid)
 
     # ------------------------------------------------------------------
     def decode_iteration(self, active: List[Slot]) -> float:
         """Advance every slot in ``active`` with a SINGLE masked jitted
-        arena call.  Without speculation (or when no slot has a draft this
-        round) that is the historical one-token decode, bit-identical to
-        pre-speculative builds.  With drafts it is ONE multi-token verify
-        step: each slot commits the longest draft prefix the target would
-        have emitted plus the bonus token (DESIGN.md §15) — token-exact
-        with sequential decode, 1..width tokens per slot per iteration.
-        Returns the measured wall seconds."""
+        arena step at width ``w`` = 1 + the longest draft this round
+        (DESIGN.md §15).  Without drafts that is the one-token decode.
+        With drafts each slot rides along at its own draft length and
+        commits the longest draft prefix the target would have emitted
+        plus the bonus token: 1..w tokens per slot per iteration.
+        Rejected draft positions never advance a slot, and a layout with
+        pages releases the tail it grew for them.  Returns the measured
+        wall seconds."""
         proposals: Dict[int, List[int]] = {}
         if self.cfg.spec_k > 0:
             spec = [s for s in active if s.spec_k > 0]
@@ -732,83 +613,25 @@ class DecodeWorker(_Placed):
                 proposals = {i: d for i, d in
                              self.draft().propose_all(items, budgets).items()
                              if d}
-        if proposals:
-            return self._verify_iteration(active, proposals)
-        mask = np.zeros(self.n_slots, bool)
-        for slot in active:
-            mask[slot.idx] = True
-        dec = self._arena_fn()
-        self.ensure_arena()
-        if self.cfg.paged:
-            # Grow each live slot to cover this step's write position —
-            # the on-demand allocation that replaces worst-case sizing.
-            for slot in active:
-                self.page_table.ensure(slot.idx,
-                                       int(self._positions[slot.idx]) + 1)
-        with tracing.timed("decode_step") as sp:
-            if self.cfg.paged:
-                nxt, self._arena = dec(
-                    self.params, self._arena, self._qcodes,
-                    self._qscales, jnp.asarray(self._block_tables()),
-                    jnp.asarray(self._quant_len),
-                    jnp.asarray(self._last_tok[:, None]),
-                    jnp.asarray(self._positions), jnp.asarray(mask))
-            else:
-                nxt, self._arena = dec(
-                    self.params, self._arena,
-                    jnp.asarray(self._last_tok[:, None]),
-                    jnp.asarray(self._positions), jnp.asarray(mask))
-            with tracing.span("token_pull"):
-                # lint: sync-ok(the step's single sanctioned sync - one batched pull)
-                nxt = np.asarray(nxt)
-            if sp:
-                sp.add(live=len(active))
-        for slot in active:
-            t = int(nxt[slot.idx])
-            slot.toks.append(t)
-            self._last_tok[slot.idx] = t
-            self._positions[slot.idx] += 1
-            if slot.spec_k > 0 and self._draft is not None:
-                self._draft.commit(slot.idx, slot.req.rid, [t])
-        self.decode_steps += 1
-        return sp.seconds
-
-    def _verify_iteration(self, active: List[Slot],
-                          proposals: Dict[int, List[int]]) -> float:
-        """One masked multi-token verify step over the arena.  Every
-        active slot rides along at its own draft length (no drafts = a
-        plain one-token step inside the wide call); rejected draft
-        positions never advance a slot and — paged — their over-ensured
-        tail pages are rolled back before the pages can leak."""
-        from repro.serving.speculative import accept_length
-        width = max(len(d) for d in proposals.values()) + 1
+        width = 1 + max(map(len, proposals.values()), default=0)
         mask = np.zeros(self.n_slots, bool)
         toks = np.zeros((self.n_slots, width), np.int32)
-        for slot in active:
-            mask[slot.idx] = True
-            toks[slot.idx, 0] = self._last_tok[slot.idx]
-            for j, d in enumerate(proposals.get(slot.idx, [])):
-                toks[slot.idx, 1 + j] = d
-        fn = self._verify_fn(width)
+        toks[:, 0] = self._last_tok
+        step = self._arena_fn()
         self.ensure_arena()
-        if self.cfg.paged:
-            # Ensure through the worst-case commit (all drafts accepted);
-            # the rejected tail is released again right after the verify.
-            for slot in active:
-                need = (int(self._positions[slot.idx]) + 1
-                        + len(proposals.get(slot.idx, [])))
-                self.page_table.ensure(slot.idx, need)
+        for slot in active:
+            drafts = proposals.get(slot.idx, [])
+            mask[slot.idx] = True
+            toks[slot.idx, 1:1 + len(drafts)] = drafts
+            # Grow the slot through its worst-case commit (every draft
+            # accepted): on-demand allocation in place of worst-case sizing.
+            self.layout.grow(slot.idx, int(self._positions[slot.idx]) + 1
+                             + len(drafts))
         with tracing.timed("decode_step") as sp:
-            if self.cfg.paged:
-                out, self._arena = fn(
-                    self.params, self._arena, self._qcodes,
-                    self._qscales, jnp.asarray(self._block_tables()),
-                    jnp.asarray(self._quant_len), jnp.asarray(toks),
-                    jnp.asarray(self._positions), jnp.asarray(mask))
-            else:
-                out, self._arena = fn(
-                    self.params, self._arena, jnp.asarray(toks),
-                    jnp.asarray(self._positions), jnp.asarray(mask))
+            out, self._arena = step(
+                self.params, self._arena, *self.layout.operands(),
+                jnp.asarray(toks), jnp.asarray(self._positions),
+                jnp.asarray(mask))
             with tracing.span("token_pull"):
                 # lint: sync-ok(the step's single sanctioned sync - one batched pull)
                 out = np.asarray(out)
@@ -820,20 +643,21 @@ class DecodeWorker(_Placed):
             a = accept_length(drafts, row)
             needed = slot.req.out_tokens + 1 - len(slot.toks)
             c = min(a + 1, max(needed, 1))
-            committed = [int(row[j]) for j in range(c)]
+            committed = [int(t) for t in row[:c]]
             slot.toks.extend(committed)
             self._last_tok[slot.idx] = committed[-1]
             self._positions[slot.idx] += c
-            slot.verify_steps += 1
-            slot.spec_committed += c
-            slot.drafts_offered += len(drafts)
-            slot.drafts_accepted += min(a, c - 1)
-            self.spec_committed += c
+            if proposals:
+                slot.verify_steps += 1
+                slot.spec_committed += c
+                slot.drafts_offered += len(drafts)
+                slot.drafts_accepted += min(a, c - 1)
+                self.spec_committed += c
             if slot.spec_k > 0 and self._draft is not None:
                 self._draft.commit(slot.idx, slot.req.rid, committed)
-            if self.cfg.paged and drafts:
-                self.page_table.release_tail(
-                    slot.idx, int(self._positions[slot.idx]))
+            if drafts:
+                self.layout.release(slot.idx,
+                                    upto=int(self._positions[slot.idx]))
         self.decode_steps += 1
-        self.verify_steps += 1
+        self.verify_steps += bool(proposals)
         return sp.seconds

@@ -1,13 +1,13 @@
-"""The identity hand-off's injection into a dense arena on the device.
+"""The restored hand-off's injection into an arena on the device.
 
-A restored identity payload stays fp16 (a view of its bytes), crosses to
-the arena's device in one push, and one jitted program casts it and
-writes every attention layer's row.  The arena it leaves is bit-equal, on
-every leaf, to the host path's (fp16 widened to float32, cast to bf16 on
-the host, written by eager scatters), which still serves every float32
-restore; one program serves every slot; the ``inject`` span says which
-path ran (``device_cast``).  Every consumer of a restore other than the
-dense arena's injection still gets float32."""
+Whatever the restore's dtype — fp16 (a view of an identity payload's
+bytes) or float32 — K and V cross to the arena's device in one push, and
+one jitted program per dtype casts them and writes every attention
+layer's row, for every slot.  The arena it leaves is bit-equal, on every
+leaf, to a numpy oracle computed on the host: the slot's rows set to the
+payload rounded once to bf16, the state as sent, every other element as
+it was.  Every arena takes an fp16 identity restore; the engine, the
+store's re-compression and lossy restores get float32."""
 from dataclasses import replace
 from types import SimpleNamespace
 
@@ -95,9 +95,40 @@ def _assert_bit_equal(got, want):
         np.testing.assert_array_equal(g, w)
 
 
+def _oracle(cfg, arena, kv: KVCache):
+    """The arena, as numpy, after ``kv`` is written into row ``SLOT``:
+    each attention layer's positions ``:SEQ`` hold its K and V rounded
+    once to bf16, each SSM layer's state is as sent, and every other
+    element is as it was.  Layers are in hand-off order: the prefix's,
+    then each scanned block's in turn."""
+    from repro.models.transformer import plan_stack
+
+    plan = plan_stack(cfg)
+    out = jax.tree_util.tree_map(np.array, arena)
+    layers = {"attn": [], "ssm": []}
+    for i, spec in enumerate(plan.prefix_specs):
+        layers.get(spec.kind, []).append(out["prefix"][f"layer{i}"])
+    for b in range(plan.n_blocks):
+        for j, spec in enumerate(plan.period_specs):
+            leaves = out["blocks"][f"layer{j}"]
+            layers.get(spec.kind, []).append(
+                {t: a[b] for t, a in leaves.items()})   # views into out
+    assert len(layers["attn"]) == kv.k.shape[0]
+    for layer, k, v in zip(layers["attn"], kv.k, kv.v):
+        for t, x in (("k", k), ("v", v)):
+            layer[t][SLOT, :SEQ] = x.astype(ml_dtypes.bfloat16).swapaxes(0, 1)
+    for i, layer in enumerate(layers["ssm"]):
+        for t in ("ssm", "conv"):
+            layer[t][SLOT] = kv.state[t][i]
+    return out
+
+
 @pytest.mark.parametrize("case", CASES)
 def test_device_injection_is_bit_equal_to_the_host_path(case):
-    from repro.core.quality import DeviceKV, inject_kv
+    """Both restores of an identity payload, fp16 and float32, leave the
+    arena the host's numpy oracle (:func:`_oracle`) computes, bit for
+    bit."""
+    from repro.core.quality import inject_kv
 
     cfg = _model(case)
     arena = _arena(cfg, seed=1)
@@ -110,18 +141,12 @@ def test_device_injection_is_bit_equal_to_the_host_path(case):
     assert not narrow.k.flags.owndata and not narrow.v.flags.owndata
     assert np.shares_memory(narrow.k, np.frombuffer(comp.identity_payload,
                                                     np.float16))
-    host = inject_kv(cfg, arena, SLOT, wide)
-    device = inject_kv(cfg, arena, SLOT, narrow)
-    _assert_bit_equal(device, host)
-    # and the slot holds the payload rounded once to bf16, state as sent
-    back = DeviceKV(cfg, device, SLOT, SEQ).host()
-    for got, sent in ((back.k, kv.k), (back.v, kv.v)):
-        want = sent.astype(ml_dtypes.bfloat16).astype(np.float32)
-        np.testing.assert_array_equal(got.view(np.uint32),
-                                      want.view(np.uint32))
-    assert (back.state is None) == (kv.state is None)
-    for t in kv.state or {}:
-        np.testing.assert_array_equal(back.state[t], kv.state[t])
+    want = _oracle(cfg, arena, kv)
+    # the oracle moved the slot's rows, and nothing of the arena it read
+    assert any(not np.array_equal(w, a) for w, a in zip(_bits(want),
+                                                         _bits(arena)))
+    for restore in (narrow, wide):
+        _assert_bit_equal(inject_kv(cfg, arena, SLOT, restore), want)
     if case == "fp16_extremes":
         assert np.isin(np.float16(65504.0), narrow.k)
         assert (np.abs(narrow.k) < np.finfo(np.float16).tiny).sum() > 1000
@@ -138,6 +163,10 @@ def _decode_worker(cfg, n_slots: int, paged: bool = False):
 
 def test_one_program_serves_every_slot_and_the_span_says_where_it_cast(
         tmp_path):
+    """One compiled program per restore dtype serves every slot: four
+    fp16 restores compile once, and two float32 restores (the harness's
+    warm-up hand-off, a lossy restore) once more; the ``inject`` span
+    counts the bytes written and no compile after each dtype's first."""
     from repro.core.quality import DeviceKV, _scatter_kv
     from repro.serving import tracing
 
@@ -148,7 +177,7 @@ def test_one_program_serves_every_slot_and_the_span_says_where_it_cast(
     dw._arena = jax.device_put(dw.ensure_arena(), jax.devices()[0])
     pipe = CompressionPipeline(IDENTITY_PROFILE.strategy)
     sent = [_handoff(cfg, dw._arena, "gqa_prefix_scan", seed=10 + s)
-            for s in range(5)]
+            for s in range(6)]
     comps = [pipe.compress(kv) for kv in sent]
     _scatter_kv.clear_cache()
     tracing.SPANS.clear()
@@ -156,19 +185,19 @@ def test_one_program_serves_every_slot_and_the_span_says_where_it_cast(
         for s in range(4):
             dw.inject_restored(pipe.decompress(comps[s], fp16=True), s)
         compiled = _scatter_kv._cache_size()
-        # a float32 restore (the harness's warm-up hand-off, a lossy
-        # restore) takes the host path into slot 1
-        dw.inject_restored(pipe.decompress(comps[4]), 1)
+        for s, i in ((1, 4), (3, 5)):
+            dw.inject_restored(pipe.decompress(comps[i]), s)
         jax.block_until_ready(dw._arena)
     spans = [s for s in tracing.SPANS if s.name == "inject"]
     tracing.SPANS.clear()
-    assert compiled == 1 and _scatter_kv._cache_size() == 1
-    assert [s.counters["device_cast"] for s in spans] == [1, 1, 1, 1, 0]
-    assert all("compiles" not in s.counters for s in spans[1:4]), \
+    assert compiled == 1 and _scatter_kv._cache_size() == 2
+    assert len(spans) == 6
+    assert all("compiles" not in s.counters for s in spans[1:4] + spans[5:]),\
         [s.counters for s in spans]
+    assert all("device_cast" not in s.counters for s in spans)
     n_kv = sent[0].k.size + sent[0].v.size
     assert all(s.counters["bytes"] == 2 * n_kv for s in spans)
-    for s, kv in zip((0, 1, 2, 3), (sent[0], sent[4], sent[2], sent[3])):
+    for s, kv in zip((0, 1, 2, 3), (sent[0], sent[4], sent[2], sent[5])):
         back = DeviceKV(cfg, dw._arena, s, SEQ).host()
         for got, want in ((back.k, kv.k), (back.v, kv.v)):
             np.testing.assert_array_equal(
@@ -186,6 +215,7 @@ def _tiny_lm():
 KIVI = Profile(BASELINES["kivi"], cr=4.0, s_enc=1e8, s_dec=1e8)
 CONSUMERS = ["engine", "recompress", "paged_fetch", "pd_paged", "lossy",
              "dense_fetch", "pd_dense"]
+ARENAS = {"paged_fetch", "pd_paged", "dense_fetch", "pd_dense"}
 
 
 def _identity_entry(cfg):
@@ -250,6 +280,8 @@ def _drive(consumer: str, monkeypatch):
 
 @pytest.mark.parametrize("consumer", CONSUMERS)
 def test_only_the_dense_arena_takes_an_fp16_restore(consumer, monkeypatch):
+    """Every arena, dense or paged, takes an fp16 identity restore; the
+    engine, the store's re-compression and lossy restores get float32."""
     seen = _drive(consumer, monkeypatch)
     identity = [dt for ident, *dt in seen if ident]
     lossy = [dt for ident, *dt in seen if not ident]
@@ -258,6 +290,5 @@ def test_only_the_dense_arena_takes_an_fp16_restore(consumer, monkeypatch):
     else:
         assert identity, consumer
     assert all(dt == [np.float32] * 2 for dt in lossy), lossy
-    want = np.float16 if consumer.startswith(("dense", "pd_dense")) \
-        else np.float32
+    want = np.float16 if consumer in ARENAS else np.float32
     assert all(dt == [want] * 2 for dt in identity), (consumer, identity)

@@ -184,7 +184,7 @@ def test_arena_decode_token_exact_vs_per_slot_loop(reference_model):
                          for i in range(n_slots)])
         nxt, arena = arena_dec(params, arena, jnp.asarray(last[:, None]),
                                jnp.asarray(pos), jnp.asarray(mask))
-        nxt = np.asarray(nxt)
+        nxt = np.asarray(nxt)[:, 0]
         for i in range(n_slots):
             if mask[i]:
                 got[i].append(int(nxt[i]))

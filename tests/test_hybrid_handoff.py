@@ -146,7 +146,8 @@ def _serve(cfg, params, prompts, strategy, fault=None):
         dw.inject_restored(kv, slot)
         first[slot] = int(np.argmax(pre_logits))
         rows[slot] = [pre_logits]
-    _, dec, arena = _jitted_steps(cfg.name, PROMPT, 3, MAX_LEN)
+    _, dec, _ = _jitted_steps(cfg.name, PROMPT, 3, MAX_LEN)
+    arena, layout = dw.layout.program(), dw.layout
     mask = jnp.asarray([s in live for s in range(3)])
     toks = np.zeros((3, 1), np.int32)
     pos = np.full(3, PROMPT, np.int32)
@@ -156,11 +157,11 @@ def _serve(cfg, params, prompts, strategy, fault=None):
     for _ in range(OUT - 1):
         # the logits the arena step takes its argmax of: the same model
         # step over the same arena, before the step replaces it
-        logits, _ = dec(params, dw._arena, jnp.asarray(toks),
+        logits, _ = dec(params, layout.cache, jnp.asarray(toks),
                         jnp.where(mask, jnp.asarray(pos), MAX_LEN - 1))
-        nxt, dw._arena = arena(params, dw._arena, jnp.asarray(toks),
-                               jnp.asarray(pos), mask)
-        nxt = np.asarray(nxt)
+        nxt, layout.cache = arena(params, layout.cache, jnp.asarray(toks),
+                                  jnp.asarray(pos), mask)
+        nxt = np.asarray(nxt)[:, 0]
         for s in live:
             rows[s].append(np.asarray(logits[s, -1], np.float32))
             served[s].append(int(nxt[s]))
@@ -216,7 +217,6 @@ def test_prefill_handoff_and_arena_decode_match_the_reference(
 def test_a_parked_slot_keeps_its_state_bit_for_bit(model):
     """The masked arena step leaves a parked slot's SSM and conv state as
     it was; the unmasked model step would have advanced it."""
-    from repro.core.quality import _jitted_steps
     from repro.models import decode_step
 
     cfg, params = model
@@ -226,12 +226,11 @@ def test_a_parked_slot_keeps_its_state_bit_for_bit(model):
         kv, _, _ = _hand_off(cfg, params, prompts[slot],
                              IDENTITY_PROFILE.strategy)
         dw.inject_restored(kv, slot)
-    _, _, arena = _jitted_steps(cfg.name, PROMPT, 2, MAX_LEN)
     toks = jnp.asarray([[3], [4]], jnp.int32)
     pos = jnp.full((2,), PROMPT, jnp.int32)
     mask = jnp.asarray([True, False])
-    before = dw._arena
-    _, after = arena(params, before, toks, pos, mask)
+    before = dw.layout.cache
+    _, after = dw.layout.program()(params, before, toks, pos, mask)
     _, unmasked = decode_step(cfg, params, before, toks, pos)
     n_state = 0
     for name, layer in before["blocks"].items():

@@ -181,8 +181,8 @@ on = lambda tree, d: all(x.devices() == {{d}}
 for w, d in zip(rt.prefill_workers, devs[:2]):
     assert on(w.params, d), w.name
 for w, d in zip(rt.decode_workers, devs[2:]):
-    assert on((w.params, w._arena, w._qcodes, w._qscales), d), w.name
-    assert (w._qcodes is not None) == {paged}
+    assert on((w.params,) + w.layout.arrays, d), w.name
+    assert (len(w.layout.arrays) == 3) == {paged}   # + the quant pools
 print("ok")
 """, devices=4)
     assert "ok" in out
@@ -227,7 +227,7 @@ assert two == one, (one, two)
 assert _scatter_kv._cache_size() >= 1
 for w, d in zip(rt.decode_workers, devs[2:]):
     assert all(x.devices() == {d}
-               for x in jax.tree_util.tree_leaves(w._arena)), w.name
+               for x in jax.tree_util.tree_leaves(w.layout.cache)), w.name
 print("ok")
 """, devices=4)
     assert "ok" in out

@@ -120,8 +120,9 @@ def test_paged_fp16_token_parity_with_pr1_fixture(reference_model, mode):
         assert out[rid]["tokens"] == rec["tokens"], rid
     # all pages returned to the pool after the run, invariants intact
     for dw in rt.decode_workers:
-        dw.page_table.check()
-        assert dw.page_table.free_pages == dw.page_table.num_pages - 1
+        dw.layout.page_table.check()
+        assert (dw.layout.page_table.free_pages
+                == dw.layout.page_table.num_pages - 1)
 
 
 @pytest.mark.slow

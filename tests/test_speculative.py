@@ -1,12 +1,14 @@
 """Speculative + lookahead decoding (DESIGN.md §15).
 
-The load-bearing guarantee is *token-exactness*: with greedy
-verification, a speculative run must emit exactly the tokens a plain
-run of the SAME model emits — drafts only change how many serial decode
-steps it takes.  The pinned scenario of ``_runtime_scenario`` is run
-spec-on vs spec-off across k ∈ {2, 4} × {dense, paged} × {pool, pd};
-the fixture-parity variant additionally pins against the PR-1 tokens
-when the trained reference model matches the fixture's digest.
+The load-bearing guarantee is exactness: with greedy verification, a
+speculative run emits the tokens the width-1 step of the SAME model
+would — drafts only change how many serial decode steps it takes.  The
+width-w step reduces in another order than the width-1 step, so the
+pinned scenario of ``_runtime_scenario`` is run speculatively across
+k ∈ {2, 4} × {dense, paged} × {pool, pd} and held to the width-1 step at
+a stated logit tolerance (``_assert_width_one_exact``); the
+fixture-parity variant pins against the PR-1 tokens when the trained
+reference model matches the fixture's digest.
 
 The n-gram proposer carries the exactness tests: its drafts reuse the
 target's own committed history, so every accepted position's KV row was
@@ -17,9 +19,12 @@ verify can differ from sequentially-written rows by ~1 bf16 ulp (the
 online-softmax merge associates differently), which can flip greedy
 argmax near-ties far downstream; see DESIGN.md §15.
 """
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
-from _runtime_scenario import build_runtime, run_scenario
+from _runtime_scenario import SCENARIO, build_runtime, run_scenario
+from repro.core.strategy import StrategyConfig
 from repro.serving.speculative import NGramDraft, accept_length
 
 
@@ -60,9 +65,118 @@ def test_ngram_draft_state_is_per_request():
 
 
 # ---------------------------------------------------------------------------
-# Token-exactness on the real tiny model
+# Exactness to the width-1 step, on the real tiny model
 # ---------------------------------------------------------------------------
+# The verify step (width w = 1 + drafts) and the width-1 step reduce in
+# different orders (DESIGN.md §15), and the byte LM's logits are bf16, so
+# on the same inputs their logits differ by a few bf16 ulps: the widest
+# difference measured (_widest_width_difference, printed) is 1.5, three
+# ulps of a top logit near 83, on the CPU.  A token the wide step chose
+# is within twice that difference of the width-1 step's best logit at
+# its position, so LOGIT_TOL is twice 1.5.
+LOGIT_TOL = 3.0
+# The scenario's profile: the KV a pool hit decodes from is its restore.
+STRATEGY = StrategyConfig(quantizer="uniform", key_bits=8, value_bits=8,
+                          granularity="per_channel")
 _BASELINES = {}
+_WIDEST = []
+
+
+def _steps(reference_model, rt):
+    from repro.core.quality import _jitted_steps
+    return _jitted_steps(reference_model[0].name, rt.cfg.seq, 1,
+                         rt.cfg.arena_max_len)
+
+
+def _prefilled(reference_model, rt, rid: int, pool_hit: bool):
+    """The prefill's last logits of request ``rid`` of the scenario, and
+    the batch-1 cache it decoded from: the prefill's, with the profile's
+    restore for a pool hit."""
+    from repro.core.pipeline import CompressionPipeline
+    from repro.core.quality import _prompts_for, extract_kv, inject_kv
+
+    cfg, params = reference_model
+    pre, _, _ = _steps(reference_model, rt)
+    workload, _, seed, _, _ = SCENARIO[rid]
+    prompt, _ = _prompts_for(workload, 1, rt.cfg.seq, seed)
+    logits, cache = pre(params, {"tokens": prompt})
+    if pool_hit:
+        pipe = CompressionPipeline(STRATEGY)
+        kv = extract_kv(cfg, cache, 0, upto=rt.cfg.seq)
+        cache = inject_kv(cfg, cache, 0, pipe.decompress(pipe.compress(kv)))
+    return np.asarray(logits[0, -1], np.float32), cache
+
+
+def _width_one_logits(reference_model, rt, cache, tokens, start=0):
+    """The width-1 step's logits after each of ``tokens``, teacher-forced
+    from position ``seq + start``: ((len(tokens), V), the cache after)."""
+    _, dec, _ = _steps(reference_model, rt)
+    rows = []
+    for j, t in enumerate(tokens):
+        logits, cache = dec(reference_model[1], cache,
+                            jnp.asarray([[t]], jnp.int32),
+                            jnp.asarray(rt.cfg.seq + start + j, jnp.int32))
+        rows.append(np.asarray(logits[0, -1], np.float32))
+    return np.stack(rows), cache
+
+
+def _widest_width_difference(reference_model, rt) -> float:
+    """The widest |logit| difference between a width-3 or width-5 step and
+    the width-1 steps over the same cache and tokens: greedy
+    continuations of the scenario's five distinct prompts, from the end
+    of the prompt and one token on.  Printed once."""
+    if not _WIDEST:
+        _, dec, _ = _steps(reference_model, rt)
+        widest = 0.0
+        for rid in (0, 1, 2, 4, 7):
+            first, cache = _prefilled(reference_model, rt, rid, False)
+            toks, c = [int(first.argmax())], cache
+            for j in range(6):
+                row, c = _width_one_logits(reference_model, rt, c,
+                                           toks[-1:], start=j)
+                toks.append(int(row[-1].argmax()))
+            narrow, after_one = _width_one_logits(reference_model, rt,
+                                                  cache, toks[:1])
+            narrow = np.concatenate([narrow, _width_one_logits(
+                reference_model, rt, after_one, toks[1:6], start=1)[0]])
+            for start, c in ((0, cache), (1, after_one)):
+                for width in (3, 5):
+                    logits, _ = dec(
+                        reference_model[1], c,
+                        jnp.asarray([toks[start:start + width]], jnp.int32),
+                        jnp.asarray(rt.cfg.seq + start, jnp.int32))
+                    widest = max(widest, float(np.abs(
+                        np.asarray(logits[0], np.float32)
+                        - narrow[start:start + width]).max()))
+        print(f"widest logit difference, width 3/5 vs 1: {widest}")
+        _WIDEST.append(widest)
+    return _WIDEST[0]
+
+
+def _assert_width_one_exact(reference_model, rt, out, base):
+    """The bench's check in miniature: each request's tokens, the
+    committed ones teacher-forced through the width-1 step over the KV
+    the request decoded from, are each within LOGIT_TOL of that
+    position's best logit, and equal to the argmax up to the first
+    position whose top two logits are closer than LOGIT_TOL.  The run
+    serves the plain run's requests, hits and lengths."""
+    assert _widest_width_difference(reference_model, rt) <= LOGIT_TOL
+    shape = {rid: (r["workload"], r["pool_hit"], len(r["tokens"]))
+             for rid, r in base.items()}
+    assert {rid: (r["workload"], r["pool_hit"], len(r["tokens"]))
+            for rid, r in out.items()} == shape
+    for rid, rec in out.items():
+        toks = rec["tokens"]
+        first, cache = _prefilled(reference_model, rt, int(rid),
+                                  rec["pool_hit"])
+        logits, _ = _width_one_logits(reference_model, rt, cache, toks[:-1])
+        logits = np.concatenate([first[None], logits])   # row j: toks[j]
+        gap = logits.max(-1) - logits[np.arange(len(toks)), toks]
+        assert gap.max() <= LOGIT_TOL, (rid, gap)
+        top2 = np.sort(logits, -1)[:, -2:]
+        near = np.flatnonzero(top2[:, 1] - top2[:, 0] < LOGIT_TOL)
+        upto = near[0] if near.size else len(toks)
+        assert toks[:upto] == logits[:upto].argmax(-1).tolist(), rid
 
 
 def _baseline(reference_model, mode, paged):
@@ -82,7 +196,7 @@ def test_ngram_speculation_token_exact_vs_plain_decode(
     base = _baseline(reference_model, mode, paged)
     rt = build_runtime(reference_model, mode=mode, paged=paged, spec_k=k)
     out = run_scenario(rt)
-    assert out == base
+    _assert_width_one_exact(reference_model, rt, out, base)
     # speculation actually engaged and compressed serial steps
     done = rt.completed
     steps = sum(r.verify_steps for r in done)
@@ -247,7 +361,8 @@ def test_adaptive_spec_k_flows_controller_to_slots(reference_model):
     for pw in rt.prefill_workers:
         pw.controller = spy
     out = run_scenario(rt)
-    assert out == _baseline(reference_model, "pool", False)
+    _assert_width_one_exact(reference_model, rt, out,
+                            _baseline(reference_model, "pool", False))
     # the controller's pick was capped at cfg.spec_k
     assert all(r.spec_k == 3 for r in rt.completed if not r.pool_hit)
     # realized accept rates fed back for every request that offered drafts
@@ -420,6 +535,7 @@ def test_sanitizer_silent_under_paged_speculative_run(reference_model):
     try:
         rt = build_runtime(reference_model, paged=True, spec_k=4)
         out = run_scenario(rt)
-        assert out == _baseline(reference_model, "pool", True)
+        _assert_width_one_exact(reference_model, rt, out,
+                                _baseline(reference_model, "pool", True))
     finally:
         sanitize.uninstall()

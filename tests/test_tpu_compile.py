@@ -98,8 +98,9 @@ def test_qwen3_4b_serving_steps_fit_one_v5e(one_chip):
     its registered widths, in bf16, each compiled for one v5e chip and
     within its HBM."""
     from repro.configs import get_config
-    from repro.core.quality import _jitted_steps, _paged_steps, init_paged_pools
+    from repro.core.quality import _jitted_steps
     from repro.models import init_cache, init_params
+    from repro.serving.arena import _paged_steps, init_paged_pools
     from repro.serving.workers import RuntimeConfig
 
     cfg = get_config("qwen3-4b")
